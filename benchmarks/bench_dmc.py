@@ -22,7 +22,8 @@ def _run_spmd_dmc(n_dev: int, walkers_per_shard: int = 128,
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_dev}"
         import time, jax
         from repro.apps import dmc
-        mesh = jax.make_mesh(({n_dev},), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(({n_dev},), ("data",))
         # warmup compile
         dmc.run_parallel(mesh, n_walkers={walkers_per_shard * n_dev},
                          timesteps=2, tau=0.02)
@@ -35,7 +36,9 @@ def _run_spmd_dmc(n_dev: int, walkers_per_shard: int = 128,
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=420,
-                         env=dict(os.environ,
+                         # virtual CPU devices by design: never contend
+                         # for an accelerator the parent may hold
+                         env=dict(os.environ, JAX_PLATFORMS="cpu",
                                   PYTHONPATH=os.path.join(root, "src")))
     assert out.returncode == 0, out.stderr[-2000:]
     line = [l for l in out.stdout.splitlines() if l.startswith("RESULT")][0]

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.core.runtime import (MeshExecutor, SerialExecutor,
                                 ThreadFarmExecutor, VmapExecutor)
+from repro.launch.mesh import make_mesh
 
 
 def _farm_speedup(csv_rows, name, thunks, workers=8):
@@ -80,7 +81,7 @@ def run(csv_rows: list):
     def finalize(out):
         return np.asarray(out)
 
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     execs = {"serial": SerialExecutor(), "vmap": VmapExecutor(),
              "mesh": MeshExecutor(mesh),
              "thread": ThreadFarmExecutor(num_workers=8)}

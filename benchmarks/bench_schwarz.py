@@ -18,8 +18,9 @@ def _run(n_dev: int, steps: int = 40, ny: int = 64) -> dict:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_dev}"
         import time, jax, numpy as np
         from repro.apps import boussinesq as bq
+        from repro.launch.mesh import make_mesh
         p = bq.BoussinesqParams(nx=64, ny={ny}, dt=0.02, eps=0.3, alpha=0.05)
-        mesh = jax.make_mesh(({n_dev},), ("data",))
+        mesh = make_mesh(({n_dev},), ("data",))
         bq.run_parallel(mesh, p, steps=2)        # warmup
         t0 = time.perf_counter()
         eta_p, phi_p, hist = bq.run_parallel(mesh, p, steps={steps})
@@ -32,7 +33,9 @@ def _run(n_dev: int, steps: int = 40, ny: int = 64) -> dict:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=420,
-                         env=dict(os.environ,
+                         # virtual CPU devices by design: never contend
+                         # for an accelerator the parent may hold
+                         env=dict(os.environ, JAX_PLATFORMS="cpu",
                                   PYTHONPATH=os.path.join(root, "src")))
     assert out.returncode == 0, out.stderr[-2000:]
     line = [l for l in out.stdout.splitlines() if l.startswith("RESULT")][0]

@@ -52,9 +52,9 @@ def main() -> None:
         with open(path, "w") as f:
             json.dump(payload, f, indent=2, sort_keys=True)
         print(f"[bench] wrote {path}")
-    if only and failed:
-        # a specifically requested bench must not fail green (CI gates on it)
-        sys.exit(1)
+    if failed:
+        # a failed bench must never exit green, alone or in the whole suite
+        sys.exit(f"[bench] failed: {', '.join(failed)}")
 
 
 if __name__ == '__main__':

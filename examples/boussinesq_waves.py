@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from repro.apps import boussinesq as bq
+from repro.launch.mesh import make_mesh
 
 p = bq.BoussinesqParams(nx=64, ny=64, dt=0.02, eps=0.3, alpha=0.05)
 steps = 60
@@ -21,7 +22,7 @@ print(f"   mass drift: {abs(float(hist_s['mass'][-1] - hist_s['mass'][0])):.2e}"
 
 n_dev = jax.device_count()
 print(f"== additive Schwarz over {n_dev} subdomain(s) ==")
-mesh = jax.make_mesh((n_dev,), ("data",))
+mesh = make_mesh((n_dev,), ("data",))
 eta_p, phi_p, hist_p = bq.run_parallel(mesh, p, steps=steps)
 err = np.abs(np.asarray(eta_s) - np.asarray(eta_p)).max()
 print(f"   max |eta_serial - eta_schwarz| = {err:.2e}")

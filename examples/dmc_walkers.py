@@ -10,6 +10,7 @@ import jax
 import numpy as np
 
 from repro.apps import dmc
+from repro.launch.mesh import make_mesh
 
 print("== serial DMC (paper's time_integration + Walkers class) ==")
 out = dmc.run_serial(n_walkers=400, timesteps=500, tau=0.02)
@@ -18,7 +19,7 @@ print(f"   final population: {int(out['counts'][-1])}")
 
 n_dev = jax.device_count()
 print(f"== SPMD DMC over {n_dev} device(s), load-balanced every step ==")
-mesh = jax.make_mesh((n_dev,), ("data",))
+mesh = make_mesh((n_dev,), ("data",))
 out = dmc.run_parallel(mesh, n_walkers=128 * n_dev, timesteps=400, tau=0.02)
 lc = np.asarray(out["local_counts"])[-1]
 print(f"   E0 estimate: {float(out['e0_estimate']):.4f}")

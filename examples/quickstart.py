@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.core import solve_problem, vmap_solve_problem, parallel_solve_problem
 from repro.core.runtime import ThreadFarmExecutor
+from repro.launch.mesh import make_mesh
 
 M, N, L = 32, 50, 10.0
 
@@ -70,7 +71,7 @@ n_neg = vmap_solve_problem(initialize, func, finalize)
 print(f"   {n_neg} negative combinations (matches: {n_neg == len(ab)})")
 
 print("== tier 3: SPMD task farm over the available mesh ==")
-mesh = jax.make_mesh((jax.device_count(),), ("data",))
+mesh = make_mesh((jax.device_count(),), ("data",))
 n_neg = parallel_solve_problem(initialize, func, finalize, mesh)
 print(f"   {n_neg} negative combinations on a {jax.device_count()}-device mesh")
 assert n_neg == len(ab)

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.comm import Comm, make_comm, shard_map
+from repro.core.comm import Comm, make_comm
 from repro.core.runtime import (Executor, MeshExecutor, SerialExecutor,
                                 make_executor)
 from repro.core.schwarz import (additive_schwarz_iterations, halo_exchange,
@@ -262,7 +262,7 @@ def run_parallel(mesh, p: BoussinesqParams, steps: int, *, k_mode: int = 1,
         (eta, phi), hist = jax.lax.scan(body, (eta, phi), None, length=steps)
         return eta[1:-1], phi[1:-1], hist
 
-    run = shard_map(
+    run = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None)),
         out_specs=(P(axis, None), P(axis, None),

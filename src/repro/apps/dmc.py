@@ -30,7 +30,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.comm import Comm, SerialComm, make_comm, shard_map
+from repro.core.comm import Comm, SerialComm, make_comm
 from repro.core.load_balance import dynamic_load_balancing
 from repro.core.runtime import Executor, make_executor
 from repro.core.time_integration import time_integration
@@ -245,7 +245,7 @@ def run_parallel(mesh, n_walkers: int = 512, timesteps: int = 200, *,
             obs["local_count"] = obs["local_count"][:, None]    # (T, 1)
             return obs
 
-        return shard_map(
+        return jax.shard_map(
             per_shard, mesh=mesh, in_specs=P(),
             out_specs={"e_trial": P(), "count_after": P(), "pot": P(),
                        "rebalanced": P(), "local_count": P(None, axis)},

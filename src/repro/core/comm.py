@@ -18,14 +18,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(axis) -> int:
-    """Version-compat static axis size: ``jax.lax.axis_size`` where it
-    exists, else the classic ``psum(1, axis)`` idiom."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis))
-    return int(jax.lax.psum(1, axis))
-
-
 @dataclasses.dataclass(frozen=True)
 class Comm:
     """Axis-bound collectives, usable inside ``shard_map``/``pmap`` bodies.
@@ -45,8 +37,8 @@ class Comm:
         """Paper's ``num_procs`` (static)."""
         if isinstance(self.axis, (tuple, list)):
             import math
-            return int(math.prod(_axis_size(a) for a in self.axis))
-        return int(_axis_size(self.axis))
+            return int(math.prod(jax.lax.axis_size(a) for a in self.axis))
+        return int(jax.lax.axis_size(self.axis))
 
     # -- collectives --------------------------------------------------------
     def all_gather(self, x, *, axis: int = 0, tiled: bool = False):
@@ -96,10 +88,7 @@ class Comm:
 
     def pvary(self, x):
         """Mark a replicated value as device-varying (vma bookkeeping)."""
-        try:
-            return jax.lax.pvary(x, self.axis)
-        except Exception:  # older jax / outside manual context
-            return x
+        return jax.lax.pcast(x, self.axis, to="varying")
 
 
 class SerialComm:
@@ -144,18 +133,3 @@ class SerialComm:
 def make_comm(axis) -> Comm | SerialComm:
     """Factory: ``axis=None`` gives the serial transport."""
     return SerialComm() if axis is None else Comm(axis)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat ``shard_map``: newer jax exposes ``jax.shard_map``
-    (with ``check_vma``); older releases only have
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep``).
-
-    The default matches jax's own (validation on); the repo's production
-    call sites pass ``check_vma=False`` explicitly, as they always have."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)

@@ -1,9 +1,11 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On TPU runtimes the kernels run compiled; everywhere else (this CPU container,
-unit tests) they execute with ``interpret=True`` — same kernel body, Python
-evaluation, bit-compatible blocking — which is how the per-kernel allclose
-tests validate them against :mod:`repro.kernels.ref`.
+On a TPU the kernels run compiled.  On the CPU backend (unit tests) they
+execute with ``interpret=True`` — same kernel body, Python evaluation,
+bit-compatible blocking — which is how the per-kernel allclose tests
+validate them against :mod:`repro.kernels.ref`.  Any other backend raises:
+an accelerator that is not a TPU must never fall back to the interpreter
+unnoticed.
 """
 from __future__ import annotations
 
@@ -19,7 +21,12 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for TPU and interpret on CPU only; "
+            f"the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_blk",

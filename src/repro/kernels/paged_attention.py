@@ -8,27 +8,28 @@ HBM->VMEM directly via a scalar-prefetched page table: BlockSpec index maps
 read ``table[b, p]`` to pick the page, so the DMA engine performs the gather
 and the online-softmax state (acc, m, l) never leaves VMEM scratch.
 
-Grid = (B, Hkv, pages_per_seq) with pages innermost: one (W*G, page_size)
-score tile per step (G = grouped q heads per KV head, W query rows stacked
-head-major so row r serves window position ``r // G``).  The causal rule is
-per row: window position w may read KV positions ``< lengths[b] + w`` —
-``lengths`` counts valid KV entries *including* window position 0's token
-(all W tokens' K/V must be written to their pages before the call).  Pages
+Grid = (B, pages_per_seq) with pages innermost.  Each step DMAs one whole
+page (all KV heads) and loops over the heads statically: one
+(W*G, page_size) score tile per head (G = grouped q heads per KV head, W
+query rows stacked head-major so row r serves window position ``r // G``).
+The causal rule is per row: window position w may read KV positions
+``< lengths[b] + w`` — ``lengths`` counts valid KV entries *including*
+window position 0's token (all W tokens' K/V must be written to their
+pages before the call).  Pages
 past the LAST row's limit are skipped with ``pl.when`` — cost is
 O(lengths + W), not O(pages_per_seq), which is the whole point of paging.
 Rows whose limit ends before a visited page contribute nothing (their
 probabilities are zeroed, not renormalized with exp(0)); dead slots
 (length 0) produce a zero row 0.
 
-W = 1 is exactly the decode kernel this file used to ship: same grid, same
-block shapes, same page gate and mask, so single-token decode stays
-bit-identical.
+W = 1 is the single-query decode kernel: same per-head math, page gate
+and mask as every wider window.
 
 Tensor-parallel serving runs this kernel INSIDE a ``shard_map`` body: q and
 the page storage arrive head-sharded (Hq/tp, Hkv/tp local heads), the page
-table and lengths replicated, and the grid's Hkv extent is the local head
-count — each device streams only its own head shard's pages, which is what
-makes the paged step's HBM traffic scale 1/tp.
+table and lengths replicated, and the in-kernel head loop runs over the
+local head count — each device streams only its own head shard's pages,
+which is what makes the paged step's HBM traffic scale 1/tp.
 """
 from __future__ import annotations
 
@@ -43,7 +44,7 @@ NEG_INF = -1e30
 
 
 def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                     scale: float, page_size: int,
+                     scale: float, page_size: int, n_heads: int,
                      n_pages: int, window: int, group: int,
                      quantized: bool = False):
     if quantized:
@@ -51,7 +52,7 @@ def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
     length = len_ref[b]
 
     @pl.when(p == 0)
@@ -64,42 +65,47 @@ def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     # reads KV positions < length + window - 1.
     @pl.when(p * page_size < length + window - 1)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # (W*G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)         # (page_size, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            # fused dequant: the int8 page tile was DMA'd HBM->VMEM (the
-            # bandwidth win) and the per-(row, head) scale is applied here
-            # in VMEM — the full-precision K/V never exists in HBM
-            k = k * ks_ref[0, :, 0][:, None]              # (page_size, 1)
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        k_pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        w_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-        valid = k_pos < length + w_row
-        s = jnp.where(valid, s, NEG_INF)
+        # One DMA brings the whole page (every KV head); heads are static
+        # slices of that VMEM tile, so each page leaves HBM once.
+        for h in range(n_heads):
+            q = q_ref[0, h].astype(jnp.float32) * scale      # (W*G, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)        # (page_size, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                # fused dequant: the int8 page tile was DMA'd HBM->VMEM (the
+                # bandwidth win) and the per-(row, head) scale is applied
+                # here in VMEM — the full-precision K/V never exists in HBM
+                k = k * ks_ref[0, :, h:h + 1]                # (page_size, 1)
+                v = v * vs_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            k_pos = p * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            w_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+            valid = k_pos < length + w_row
+            s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        pr = jnp.exp(s - m_new[:, None])
-        # A row may be fully masked on a visited page (its limit ends on an
-        # earlier page): m_new stays NEG_INF and exp(s - m_new) would be
-        # exp(0) = 1.  Zero masked probabilities explicitly — a bitwise
-        # no-op for live rows, where exp(NEG_INF - finite) underflows to 0.
-        pr = jnp.where(valid, pr, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + pr.sum(axis=-1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
-                        + jax.lax.dot_general(pr, v, (((1,), (0,)), ((), ())),
-                                              preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
+            m_prev = m_ref[h]                                # (W*G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            pr = jnp.exp(s - m_new)
+            # A row may be fully masked on a visited page (its limit ends on
+            # an earlier page): m_new stays NEG_INF and exp(s - m_new) would
+            # be exp(0) = 1.  Zero masked probabilities explicitly — a
+            # bitwise no-op for live rows, where exp(NEG_INF - finite)
+            # underflows to 0.
+            pr = jnp.where(valid, pr, 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + pr.sum(axis=-1, keepdims=True)
+            acc_ref[h] = (acc_ref[h] * corr
+                          + jax.lax.dot_general(
+                              pr, v, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32))
+            m_ref[h] = m_new
 
     @pl.when(p == n_pages - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention_mq(q, k_pages, v_pages, tables, lengths, *,
@@ -135,37 +141,39 @@ def paged_attention_mq(q, k_pages, v_pages, tables, lengths, *,
     tables = tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
 
-    def q_index(b, h, p, tbl, ln):
-        return (b, h, 0, 0)
+    def q_index(b, p, tbl, ln):
+        return (b, 0, 0, 0)
 
-    def kv_index(b, h, p, tbl, ln):
-        return (tbl[b, p], 0, h, 0)
+    def kv_index(b, p, tbl, ln):
+        return (tbl[b, p], 0, 0, 0)
 
-    def scale_index(b, h, p, tbl, ln):
-        return (tbl[b, p], 0, h)
+    def scale_index(b, p, tbl, ln):
+        return (tbl[b, p], 0, 0)
 
     kernel = functools.partial(_paged_mq_kernel, scale=scale,
-                               page_size=page_size, n_pages=P,
+                               page_size=page_size, n_heads=Hkv, n_pages=P,
                                window=W, group=G, quantized=quantized)
+    # Every block spans its array's last two dims in full, the layout the
+    # TPU compiler accepts for any Hkv, W*G and D.
     in_specs = [
-        pl.BlockSpec((1, 1, W * G, D), q_index),
-        pl.BlockSpec((1, page_size, 1, D), kv_index),
-        pl.BlockSpec((1, page_size, 1, D), kv_index),
+        pl.BlockSpec((1, Hkv, W * G, D), q_index),
+        pl.BlockSpec((1, page_size, Hkv, D), kv_index),
+        pl.BlockSpec((1, page_size, Hkv, D), kv_index),
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, 1), scale_index),
-                     pl.BlockSpec((1, page_size, 1), scale_index)]
+        in_specs += [pl.BlockSpec((1, page_size, Hkv), scale_index),
+                     pl.BlockSpec((1, page_size, Hkv), scale_index)]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, P),
+        grid=(B, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, W * G, D), q_index),
+        out_specs=pl.BlockSpec((1, Hkv, W * G, D), q_index),
         scratch_shapes=[
-            pltpu.VMEM((W * G, D), jnp.float32),
-            pltpu.VMEM((W * G,), jnp.float32),
-            pltpu.VMEM((W * G,), jnp.float32),
+            pltpu.VMEM((Hkv, W * G, D), jnp.float32),
+            pltpu.VMEM((Hkv, W * G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, W * G, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -181,8 +189,7 @@ def paged_attention_mq(q, k_pages, v_pages, tables, lengths, *,
 def paged_attention(q, k_pages, v_pages, tables, lengths, *,
                     k_scale=None, v_scale=None, interpret: bool = False):
     """Single-query decode: q (B, Hq, D) -> (B, Hq, D).  W=1 window of
-    :func:`paged_attention_mq` (bit-identical to the original decode
-    kernel); ``lengths`` includes the current token."""
+    :func:`paged_attention_mq`; ``lengths`` includes the current token."""
     return paged_attention_mq(q[:, None], k_pages, v_pages, tables, lengths,
                               k_scale=k_scale, v_scale=v_scale,
                               interpret=interpret)[:, 0]

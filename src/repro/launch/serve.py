@@ -19,15 +19,65 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+import pathlib
 import time
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, list_archs, smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models.api import build_model
 from repro.serve import DisaggServeEngine, ServeEngine, make_workload, \
     run_traffic
+from repro.serve.engine import serve_param_specs
+
+# the source checkout this module runs from (<repo>/src/repro/launch/serve.py)
+_SOURCE_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left to JAX.  Otherwise the cache lives at ``<repo>/.jax_cache`` of the
+    source checkout this module runs from; an installed package has no
+    checkout, and then the variable must be set.  Entry points call this
+    once before their first compile; importing a module never touches the
+    cache."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if not (_SOURCE_ROOT / "pyproject.toml").is_file():
+        raise RuntimeError(
+            f"{_SOURCE_ROOT} is not a repro checkout; set "
+            "JAX_COMPILATION_CACHE_DIR to choose the compile cache directory")
+    path = str(_SOURCE_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def init_model(cfg, *, seed: int = 0, mesh=None, paged: bool = True):
+    """``build_model(cfg)`` plus random weights from ``PRNGKey(seed)``.
+
+    Under a serving ``mesh`` the weights are created directly into the
+    shardings the engine serves them with, so no device ever holds the whole
+    tree (a 7B model in float32 does not fit one 16 GB chip).  ``paged``
+    says whether the engine will serve paged (head-sharded TP) or dense
+    (slot-parallel, params replicated)."""
+    model = build_model(cfg)
+    key = jax.random.PRNGKey(seed)
+    out_shardings = None
+    if mesh is not None:
+        specs = serve_param_specs(
+            model, jax.eval_shape(model.init, key), mesh,
+            paged=paged and model.supports_paged_decode())
+        out_shardings = jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, P))
+    return model, jax.jit(model.init, out_shardings=out_shardings)(key)
 
 
 def parse_mesh(spec: str | None):
@@ -55,8 +105,8 @@ def parse_mesh(spec: str | None):
                          "visible (set XLA_FLAGS=--xla_force_host_platform_"
                          "device_count=N on CPU)")
     if ep is None:
-        return jax.make_mesh((tp,), ("model",))
-    return jax.make_mesh((ep, tp), ("expert", "model"))
+        return make_mesh((tp,), ("model",))
+    return make_mesh((ep, tp), ("expert", "model"))
 
 
 def encoder_workload_kwargs(cfg, args) -> dict:
@@ -208,6 +258,7 @@ def main():
                     help="fraction of requests carrying an image with "
                     "--image (audio is always 1.0 — enc-dec needs a clip)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.disagg and args.dense:
         raise SystemExit("--disagg needs the paged KV engine; drop --dense")
 
@@ -228,9 +279,8 @@ def main():
     if (args.image or args.audio) and args.disagg:
         raise SystemExit("--image/--audio are wired through the monolithic "
                          "engine; drop --disagg")
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
     mesh = parse_mesh(args.mesh)
+    model, params = init_model(cfg, seed=0, mesh=mesh, paged=not args.dense)
     kw = dict(max_slots=args.slots, max_len=args.max_len,
               page_size=args.page_size, num_pages=args.num_pages,
               prefill_chunk=args.prefill_chunk,

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.comm import Comm
-from repro.core.comm import shard_map as _comm_shard_map
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:
@@ -85,7 +84,7 @@ def pipeline_apply(stage_fn: Callable, params_stacked, x_micro, mesh,
         outs = comm.broadcast_from(outs, root=n_stages - 1)
         return outs
 
-    return jax.jit(_comm_shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_specs, P()),
         out_specs=P(),

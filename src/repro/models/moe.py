@@ -27,7 +27,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.comm import Comm, SerialComm
-from repro.core.comm import shard_map as _comm_shard_map
 from repro.mesh.axes import AxisRules, logical_to_mesh
 from repro.models.module import Param
 
@@ -292,7 +291,7 @@ def moe_apply(params, x, cfg, rules: AxisRules | None):
         aux = Comm(mesh.axis_names).all_reduce_sum(aux) / mesh.size
         return y.reshape(B_l, S_l, d), aux
 
-    y, aux = _comm_shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, w_specs["router"], w_specs["gate"], w_specs["up"],
                   w_specs["down"]),

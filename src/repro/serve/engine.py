@@ -50,7 +50,8 @@ work is committed).
 
 from __future__ import annotations
 
-__all__ = ["Request", "ServeEngine", "encoder_prefix_tokens"]
+__all__ = ["Request", "ServeEngine", "encoder_prefix_tokens",
+           "serve_param_specs"]
 
 import dataclasses
 import functools
@@ -63,7 +64,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import comm as CC
 from repro.core.comm import Comm
 from repro.core.runtime import ThreadFarmExecutor
 from repro.serve import pages as PG
@@ -113,6 +113,24 @@ def _bucket(n: int, buckets=(32, 64, 128, 256, 512, 1024, 2048, 4096)) -> int:
         if n <= b:
             return b
     return ((n + 4095) // 4096) * 4096
+
+
+def serve_param_specs(model, params, mesh, *, paged: bool):
+    """PartitionSpecs a mesh engine serves float ``params`` with on
+    ``mesh``.
+
+    Paged families run head-sharded TP (+ expert-partitioned EP) per
+    ``model.serve_param_specs``, once ``validate_serve_mesh`` has named
+    every dim the mesh does not divide.  Slot-parallel serving runs the
+    unchanged step fn per shard, so params must be REPLICATED whatever the
+    family's TP specs would say (a dense-forced DecoderLM included).
+    ``params`` may be shapes (``jax.eval_shape``): only its tree and ranks
+    are read."""
+    if paged:
+        ep = int(mesh.shape["expert"]) if "expert" in mesh.axis_names else 1
+        model.validate_serve_mesh(tp=int(mesh.shape["model"]), ep=ep)
+        return model.serve_param_specs(ep=ep)
+    return jax.tree_util.tree_map(lambda a: P(*([None] * a.ndim)), params)
 
 
 def encoder_prefix_tokens(enc: np.ndarray) -> np.ndarray:
@@ -314,8 +332,7 @@ class ServeEngine:
                 if "expert" in mesh.axis_names else 1
             if self.paged:
                 # head-sharded TP (+ expert-partitioned EP): family specs
-                model.validate_serve_mesh(tp=self.tp, ep=self.ep)
-                pspecs = model.serve_param_specs(ep=self.ep)
+                pspecs = serve_param_specs(model, params, mesh, paged=True)
                 if self.weight_quant:
                     # int8 payload keeps the weight's spec; scalar scales
                     # replicate — dequant commutes with sharding, so tp=N
@@ -327,11 +344,7 @@ class ServeEngine:
                         f"expert-parallel serving needs the paged MoE path: "
                         f"{model.cfg.name} ({model.cfg.family}) is serving "
                         "non-paged (slot-parallel); drop the expert axis")
-                # slot-parallel: the step fn runs unchanged per shard, so
-                # params must be REPLICATED whatever the family's TP specs
-                # would say (a dense-forced DecoderLM included)
-                pspecs = jax.tree_util.tree_map(
-                    lambda a: P(*([None] * jnp.ndim(a))), params)
+                pspecs = serve_param_specs(model, params, mesh, paged=False)
             self._param_shardings = jax.tree_util.tree_map(
                 lambda s: NamedSharding(mesh, s), pspecs)
             self.params = params = jax.device_put(params,
@@ -409,14 +422,12 @@ class ServeEngine:
                       "expert_imbalance": 0.0, "placement_updates": 0}
 
         # donate the state/storage argument so XLA updates the KV buffers in
-        # place (no full-pool copy per tick); CPU has no donation support
-        # and would only warn
-        donate = () if jax.default_backend() == "cpu" else (1,)
+        # place (no full-pool copy per tick)
+        donate = (1,)
         rep = P()
         if self.paged:
             if num_pages is None:       # dense-equivalent budget by default
                 num_pages = -(-max_slots * max_len // page_size)
-            cow_donate = () if jax.default_backend() == "cpu" else (0,)
             if mesh is None:
                 self.pool = PagePool(model.paged_leaf_specs(kvq),
                                      num_pages=num_pages, page_size=page_size,
@@ -424,7 +435,7 @@ class ServeEngine:
                 self._cow_copy = jax.jit(
                     lambda st, s, d: PG.copy_pages(st, self.pool.leaf_specs,
                                                    s, d),
-                    donate_argnums=cow_donate)
+                    donate_argnums=(0,))
                 self._decode_paged = jax.jit(
                     lambda p, st, tb, ln, t, wp, wo, pl:
                     model.paged_decode_step(
@@ -501,13 +512,13 @@ class ServeEngine:
                     else None
                 # COW copies move whole pages along the (replicated) page
                 # axis — each shard copies its local heads independently
-                self._cow_copy = jax.jit(CC.shard_map(
+                self._cow_copy = jax.jit(jax.shard_map(
                     lambda st, s, d: PG.copy_pages(st, self.pool.leaf_specs,
                                                    s, d),
                     mesh=mesh, in_specs=(sspecs, rep, rep),
                     out_specs=sspecs, check_vma=False),
-                    donate_argnums=cow_donate)
-                self._decode_paged = jax.jit(CC.shard_map(
+                    donate_argnums=(0,))
+                self._decode_paged = jax.jit(jax.shard_map(
                     lambda p, st, tb, ln, t, wp, wo, pl:
                     model.paged_decode_step(
                         deq(p), st, tb, ln, t, wp, wo, None,
@@ -517,7 +528,7 @@ class ServeEngine:
                     in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep, rep),
                     out_specs=(sspecs, rep, rep), check_vma=False),
                     donate_argnums=donate)
-                self._prefill_chunk = jax.jit(CC.shard_map(
+                self._prefill_chunk = jax.jit(jax.shard_map(
                     lambda p, st, row, pg, s0, t, pl:
                     model.paged_prefill_chunk(
                         deq(p), st, row, pg, s0, t, None,
@@ -527,7 +538,7 @@ class ServeEngine:
                     in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep),
                     out_specs=(sspecs, rep, rep), check_vma=False),
                     donate_argnums=donate)
-                self._verify_paged = jax.jit(CC.shard_map(
+                self._verify_paged = jax.jit(jax.shard_map(
                     lambda p, st, tb, ln, t, wp, wo, pl: model.paged_verify(
                         deq(p), st, tb, ln, t, wp, wo, None,
                         use_pallas=use_pallas_attention, comm=comm,
@@ -540,7 +551,7 @@ class ServeEngine:
                     # image embeds replicate like the token chunk (the
                     # prefix rides the replicated activation path; heads
                     # shard inside the model as usual)
-                    self._prefill_chunk_embeds = jax.jit(CC.shard_map(
+                    self._prefill_chunk_embeds = jax.jit(jax.shard_map(
                         lambda p, st, row, pg, s0, t, em, pl:
                         model.paged_prefill_chunk(
                             deq(p), st, row, pg, s0, t, None,
@@ -571,11 +582,10 @@ class ServeEngine:
                     lambda p, fr, s0, nv: model.cross_kv_chunk(
                         deq(p),
                         model.encode_chunk(deq(p), fr, s0, nv, rules)))
-                cdonate = () if jax.default_backend() == "cpu" else (0,)
                 self._scatter_cross = jax.jit(
                     lambda st, pg, k, v: model.scatter_cross(
                         st, pg, k, v, page_size=page_size, quant=kvq),
-                    donate_argnums=cdonate)
+                    donate_argnums=(0,))
             self.sched = Scheduler(max_slots=max_slots, max_len=max_len,
                                    pool=self.pool,
                                    prefill_chunk=prefill_chunk,
@@ -604,7 +614,7 @@ class ServeEngine:
                 self._fresh_state = lambda: jax.device_put(
                     model.init_decode_state(max_slots, max_len), st_sh)
                 self.state = self._fresh_state()
-                self._decode = jax.jit(CC.shard_map(
+                self._decode = jax.jit(jax.shard_map(
                     lambda p, s, t, pos: model.decode_step(p, s, t, pos,
                                                            None),
                     mesh=mesh,
@@ -900,12 +910,12 @@ class ServeEngine:
 
     def _recover_donated_storage(self):
         """A raising jitted call may already have CONSUMED the donated
-        storage buffers (non-CPU backends donate them for in-place KV
-        updates).  The KV contents are unrecoverable, so evict every
-        resident request — recompute flavor: their generated tokens
-        re-prefill on re-admission, so greedy streams survive — and rebuild
-        zeroed storage with the original shapes/shardings.  On CPU
-        (donation disabled) this is a no-op and the healthy slots keep
+        storage buffers (donated for in-place KV updates).  The KV contents
+        are unrecoverable, so evict every resident request — recompute
+        flavor: their generated tokens re-prefill on re-admission, so
+        greedy streams survive — and rebuild zeroed storage with the
+        original shapes/shardings.  When the call failed before running
+        (the buffers are intact) this is a no-op and the healthy slots keep
         their caches."""
         if self.pool is None or not self.pool.storage_deleted():
             return

@@ -20,6 +20,7 @@ def run_spmd(body: str, n_devices: int = 8, timeout: int = 420) -> str:
         import warnings; warnings.filterwarnings("ignore")
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ,
                PYTHONPATH=os.path.join(ROOT, "src"))
@@ -32,7 +33,7 @@ def run_spmd(body: str, n_devices: int = 8, timeout: int = 420) -> str:
 def test_parallel_solve_problem_task_farm():
     run_spmd("""
         from repro.core import parallel_solve_problem
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         m = 12  # 144 tasks over 8 shards (not divisible: pad+mask path)
         def initialize():
             a = jnp.linspace(-1, 1, m); b = jnp.linspace(-1, 1, m)
@@ -53,7 +54,7 @@ def test_redistribute_work_across_shards():
     run_spmd("""
         from repro.core.comm import Comm
         from repro.core.load_balance import redistribute_work
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cap = 16
         def per_shard(x):
             comm = Comm("data")
@@ -64,8 +65,7 @@ def test_redistribute_work_across_shards():
             new_data, new_count = redistribute_work(data, count, comm)
             return new_data, new_count.reshape(1)
         x = jnp.tile(jnp.arange(cap, dtype=jnp.float32)[:, None], (8, 1))
-        from repro.core.comm import shard_map
-        f = jax.jit(shard_map(per_shard, mesh=mesh,
+        f = jax.jit(jax.shard_map(per_shard, mesh=mesh,
                     in_specs=P("data", None),
                     out_specs=(P("data", None), P("data")), check_vma=False))
         data, counts = f(x)
@@ -85,7 +85,7 @@ def test_redistribute_work_across_shards():
 def test_dmc_parallel_with_load_balancing():
     run_spmd("""
         from repro.apps import dmc
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         out = dmc.run_parallel(mesh, n_walkers=512, timesteps=400, tau=0.02)
         e0 = float(out["e0_estimate"])
         assert abs(e0 - 1.5) < 0.2, e0
@@ -101,7 +101,7 @@ def test_boussinesq_schwarz_matches_serial():
         from repro.apps import boussinesq as bq
         p = bq.BoussinesqParams(nx=48, ny=48, dt=0.02, eps=0.3, alpha=0.05)
         eta_s, phi_s, hist_s = bq.run_serial(p, steps=40)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         eta_p, phi_p, hist_p = bq.run_parallel(mesh, p, steps=40)
         err = np.abs(np.asarray(eta_s) - np.asarray(eta_p)).max()
         assert err < 1e-5, err
@@ -133,7 +133,7 @@ def test_sharded_train_step_matches_single_device():
         o1, m1 = step1(s1, batch)
 
         # 4x2 mesh
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = rules_for_mesh(mesh)
         sh = state_shardings(model, mesh, rules)
         s2 = jax.device_put({"params": params, "opt": adamw_init(params, opt)}, sh)
@@ -161,7 +161,7 @@ def test_compressed_pod_dp_matches_uncompressed():
         model = build_model(cfg)
         task = SyntheticTask(cfg, batch=8, seq_len=32)
         opt = AdamWConfig(peak_lr=3e-3, warmup_steps=0, decay_steps=20)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         rules = rules_for_mesh(mesh)
 
         def run(compress):
@@ -210,7 +210,7 @@ def test_elastic_reshard_resume_across_mesh_sizes():
         params = model.init(jax.random.PRNGKey(0))
         opt = AdamWConfig(peak_lr=1e-2, warmup_steps=0, decay_steps=10)
 
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = make_mesh((4, 2), ("data", "model"))
         rules1 = rules_for_mesh(mesh1)
         sh1 = state_shardings(model, mesh1, rules1)
         state = jax.device_put({"params": params,
@@ -221,7 +221,7 @@ def test_elastic_reshard_resume_across_mesh_sizes():
         with tempfile.TemporaryDirectory() as d:
             save_checkpoint(d, 1, state)
             # "cluster shrank": resume on 2x2
-            mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+            mesh2 = make_mesh((2, 2), ("data", "model"))
             rules2 = rules_for_mesh(mesh2)
             sh2 = state_shardings(model, mesh2, rules2)
             state2, step_no = restore_checkpoint(d, state, shardings=sh2)
@@ -248,7 +248,7 @@ def test_moe_ep_all_to_all_matches_serial():
         batch = task.batch_at(0)
         l1, m1 = jax.jit(lambda p, b: model.loss(p, b, None))(params, batch)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = rules_for_mesh(mesh)
         from repro.models.module import sharding_tree
         psh = sharding_tree(model.param_defs(), mesh, rules)

@@ -134,7 +134,8 @@ def test_real_program_flops_match_analytic():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import analyze
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         W = jax.ShapeDtypeStruct((6, 512, 256), jnp.bfloat16,
                                  sharding=NamedSharding(mesh, P(None, "model", None)))
         A = jax.ShapeDtypeStruct((64, 512), jnp.bfloat16,

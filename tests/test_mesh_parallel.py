@@ -16,11 +16,10 @@ def test_ring_attention_matches_full_attention():
         v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
         want = ref.flash_attention(q, k, v, causal=True)
 
-        mesh = jax.make_mesh((8,), ("sp",))
+        mesh = make_mesh((8,), ("sp",))
         def body(q, k, v):
             return ring_attention(q, k, v, Comm("sp"), causal=True)
-        from repro.core.comm import shard_map
-        got = jax.jit(shard_map(
+        got = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, "sp", None, None),) * 3,
             out_specs=P(None, "sp", None, None), check_vma=False))(q, k, v)
@@ -45,7 +44,7 @@ def test_pipeline_matches_sequential():
         def stage_fn(p, h):
             return jnp.tanh(h @ p["w"] + p["b"])
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         got = pipeline_apply(stage_fn, params, x, mesh, axis="pod")
         want = reference_apply(stage_fn, params, x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -373,7 +373,7 @@ def test_prefill_device_failure_mid_chunk_returns_pages():
 
 
 def test_prefill_failure_with_donated_storage_recovers():
-    """Non-CPU backends donate the pool storage into the jitted calls, so a
+    """The engine donates the pool storage into the jitted calls, so a
     call that raises may already have CONSUMED the buffers.  The engine
     must detect that, evict residents (recompute flavor) and rebuild zeroed
     storage — the surviving request's greedy stream still matches an

@@ -8,6 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
+
 from repro.core.runtime import (MeshExecutor, SerialExecutor,
                                 ThreadFarmExecutor, VmapExecutor,
                                 make_executor, straggler_deadline)
@@ -37,7 +39,7 @@ def _finalize(out):
 
 def _all_executors():
     execs = [SerialExecutor(), VmapExecutor(),
-             MeshExecutor(jax.make_mesh((jax.device_count(),), ("data",))),
+             MeshExecutor(make_mesh((jax.device_count(),), ("data",))),
              ThreadFarmExecutor(num_workers=4)]
     return execs
 
@@ -54,7 +56,7 @@ def test_all_executors_identical_results():
 
 def test_mesh_executor_passes_valid_mask():
     """Two-argument finalize gets padded outputs + the valid-task mask."""
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     n_tasks = 3 * jax.device_count() + 1 if jax.device_count() > 1 else 3
 
     def initialize():
@@ -123,7 +125,7 @@ def test_make_executor_specs():
     assert isinstance(make_executor("serial"), SerialExecutor)
     assert isinstance(make_executor("vmap"), VmapExecutor)
     assert isinstance(make_executor("thread"), ThreadFarmExecutor)
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     assert isinstance(make_executor("mesh", mesh=mesh), MeshExecutor)
     ex = SerialExecutor()
     assert make_executor(ex) is ex
@@ -526,7 +528,7 @@ def test_boussinesq_executor_dispatch():
     from repro.apps import boussinesq as bq
     p = bq.BoussinesqParams(nx=24, ny=24, dt=0.02)
     _, _, hist = bq.run(p, 5, executor="serial")
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     _, _, hist_m = bq.run(p, 5, executor="mesh", mesh=mesh)
     # mass of the standing wave is ~0, so compare absolutely (the Schwarz
     # iterates differ from global Jacobi only at stencil tolerance)
@@ -553,6 +555,6 @@ def test_straggler_deadline_rule():
 def test_make_executor_rejects_options_with_instance():
     with pytest.raises(ValueError, match="configure the instance"):
         make_executor(ThreadFarmExecutor(), num_workers=8)
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     with pytest.raises(ValueError, match="configure the instance"):
         make_executor(SerialExecutor(), mesh=mesh)

@@ -18,7 +18,7 @@ _STREAMS = """
     from repro.serve import ServeEngine
 
     def ep_mesh(ep, tp=1):
-        return jax.make_mesh((ep, tp), ("expert", "model"))
+        return make_mesh((ep, tp), ("expert", "model"))
 
     def streams(model, params, mesh, n_req=4, max_new=6, **kw):
         kw.setdefault("max_slots", 4); kw.setdefault("max_len", 96)
@@ -55,7 +55,7 @@ def test_ep_paged_parity_and_telemetry():
             assert eng.stats[k] == ref.stats[k], (ep, tp, k)
 
     # legacy 1-D ("model",) mesh is untouched by the expert axis
-    got, eng = streams(model, params, jax.make_mesh((2,), ("model",)),
+    got, eng = streams(model, params, make_mesh((2,), ("model",)),
                        page_size=16, prefill_chunk=32)
     assert (eng.ep, eng.tp) == (1, 2) and got == want
 

@@ -12,6 +12,7 @@ pytest process must keep 1 device), like :mod:`tests.test_distributed`.
 import pytest
 
 from repro.configs import smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models.api import build_model
 from tests.test_distributed import run_spmd
 
@@ -46,7 +47,7 @@ def test_tp_paged_parity_dense_and_moe():
         want, _ = streams(model, params, None, paged=True, page_size=8,
                           prefill_chunk=16)
         for tp in (2, 4):
-            mesh = jax.make_mesh((tp,), ("model",))
+            mesh = make_mesh((tp,), ("model",))
             got, eng = streams(model, params, mesh, paged=True, page_size=8,
                                prefill_chunk=16)
             assert eng.tp == tp
@@ -77,13 +78,13 @@ def test_tp_parity_under_preemption_and_pallas():
         return {r.rid: r.output for r in done}, eng.stats["preemptions"]
 
     want, pre1 = go(None)
-    got, pre2 = go(jax.make_mesh((2,), ("model",)))
+    got, pre2 = go(make_mesh((2,), ("model",)))
     assert pre1 >= 1 and pre2 >= 1, (pre1, pre2)
     assert got == want
 
     want, _ = streams(model, params, None, paged=True, page_size=16,
                       prefill_chunk=16, use_pallas_attention=True)
-    got, _ = streams(model, params, jax.make_mesh((2,), ("model",)),
+    got, _ = streams(model, params, make_mesh((2,), ("model",)),
                      paged=True, page_size=16, prefill_chunk=16,
                      use_pallas_attention=True)
     assert got == want
@@ -130,7 +131,7 @@ def test_tp_prefix_cache_parity():
     assert base == want
     assert s1["prefix_hits"] >= 3 and s1["cow_copies"] >= 1, s1
     for tp in (2, 4):
-        got, stats = run(jax.make_mesh((tp,), ("model",)), True)
+        got, stats = run(make_mesh((tp,), ("model",)), True)
         assert got == want, tp
         # the host-side policy is mesh-invariant, counter for counter
         for k in ("prefix_hits", "prefix_hit_tokens", "cow_copies",
@@ -143,7 +144,7 @@ def test_tp_prefix_cache_parity():
              [[5, 17, 33, 2, 9, 1, 2, 3]])
     want, s_off = run(None, False, num_pages=4, max_len=64, max_new=30)
     assert s_off["preemptions"] >= 1
-    got, s_tp = run(jax.make_mesh((2,), ("model",)), True, num_pages=4,
+    got, s_tp = run(make_mesh((2,), ("model",)), True, num_pages=4,
                     max_len=64, max_new=30)
     assert got == want and s_tp["prefix_hits"] >= 1
     print("tp prefix-cache parity OK")
@@ -186,14 +187,14 @@ def test_tp_spec_decode_parity():
         got1, s1 = streams(None, spec_decode="ngram")
         assert got1 == want, (arch, "tp=1 spec parity")
         assert s1["draft_proposed"] > 0
-        got2, s2 = streams(jax.make_mesh((2,), ("model",)),
+        got2, s2 = streams(make_mesh((2,), ("model",)),
                            spec_decode="ngram")
         assert got2 == want, (arch, "tp=2 spec parity")
         for k in ("draft_proposed", "draft_accepted", "acceptance_rate"):
             assert s1[k] == s2[k], (arch, k, s1[k], s2[k])
         # fused multi-query kernel inside the shard_map body: spec verify +
         # decode + prefill all through Pallas, still the same streams
-        got3, s3 = streams(jax.make_mesh((2,), ("model",)),
+        got3, s3 = streams(make_mesh((2,), ("model",)),
                            spec_decode="ngram", use_pallas_attention=True)
         assert got3 == want, (arch, "tp=2 spec+pallas parity")
         assert s3["draft_proposed"] > 0
@@ -217,7 +218,7 @@ def test_tp_spec_decode_parity():
 
     want, pre = tight(None)
     assert pre >= 1
-    got, _ = tight(jax.make_mesh((2,), ("model",)), spec_decode="ngram")
+    got, _ = tight(make_mesh((2,), ("model",)), spec_decode="ngram")
     assert got == want
     print("tp spec-decode parity OK")
     """)
@@ -241,12 +242,12 @@ def test_tp_quant_parity():
                              prefill_chunk=16, kv_quant="int8")
         assert eng1.stats["kv_quant"] == "int8"
         for tp in (2, 4):
-            mesh = jax.make_mesh((tp,), ("model",))
+            mesh = make_mesh((tp,), ("model",))
             got, eng = streams(model, params, mesh, paged=True, page_size=8,
                                prefill_chunk=16, kv_quant="int8")
             assert eng.tp == tp
             assert got == want, (arch, tp, "kv quant tp parity")
-        got, _ = streams(model, params, jax.make_mesh((2,), ("model",)),
+        got, _ = streams(model, params, make_mesh((2,), ("model",)),
                          paged=True, page_size=8, prefill_chunk=16,
                          kv_quant="int8", use_pallas_attention=True)
         assert got == want, (arch, "kv quant + pallas tp parity")
@@ -257,7 +258,7 @@ def test_tp_quant_parity():
     params = model.init(jax.random.PRNGKey(0))
     want, _ = streams(model, params, None, paged=True, page_size=8,
                       prefill_chunk=16, kv_quant="int8", weight_quant="int8")
-    got, eng = streams(model, params, jax.make_mesh((2,), ("model",)),
+    got, eng = streams(model, params, make_mesh((2,), ("model",)),
                        paged=True, page_size=8, prefill_chunk=16,
                        kv_quant="int8", weight_quant="int8")
     assert eng.stats["weight_quant"] == "int8"
@@ -277,7 +278,7 @@ def test_slot_parallel_recurrent_family():
     want, enga = streams(model, params, None)
     assert not enga.paged
     for tp in (2, 4):
-        got, eng = streams(model, params, jax.make_mesh((tp,), ("model",)))
+        got, eng = streams(model, params, make_mesh((tp,), ("model",)))
         assert not eng.paged and eng.tp == tp
         assert got == want, tp
 
@@ -288,11 +289,43 @@ def test_slot_parallel_recurrent_family():
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     want, _ = streams(model, params, None, paged=False)
-    got, eng = streams(model, params, jax.make_mesh((2,), ("model",)),
+    got, eng = streams(model, params, make_mesh((2,), ("model",)),
                        paged=False)
     assert not eng.paged
     assert got == want
     print("slot-parallel parity OK")
+    """)
+
+
+def test_init_model_creates_params_in_serving_shardings():
+    """The launcher's init under a mesh: every weight is created in the
+    sharding the engine serves it with (TP-split leaves never exist whole
+    on one device), matches a plain ``model.init`` from the same seed to
+    float32 rounding, and serves the streams its own values serve
+    unsharded."""
+    run_spmd(_STREAMS + """
+    from repro.launch.serve import init_model
+    from repro.serve.engine import serve_param_specs
+    cfg = smoke_config("qwen2-7b").replace(remat="none", n_heads=8,
+                                           n_kv_heads=4)
+    mesh = make_mesh((4,), ("model",))
+    model, params = init_model(cfg, seed=3, mesh=mesh)
+    plain = model.init(jax.random.PRNGKey(3))
+    leaves, tdef = jax.tree_util.tree_flatten(params)
+    specs = tdef.flatten_up_to(
+        serve_param_specs(model, plain, mesh, paged=True))
+    n_split = 0
+    for a, w, s in zip(leaves, tdef.flatten_up_to(plain), specs):
+        assert a.sharding.is_equivalent_to(NamedSharding(mesh, s), a.ndim)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=1e-6)
+        n_split += any(sh.data.shape != a.shape for sh in a.addressable_shards)
+    assert n_split > 0
+    want, _ = streams(model, jax.tree_util.tree_map(np.asarray, params),
+                      None, paged=True, page_size=8, prefill_chunk=16)
+    got, _ = streams(model, params, mesh, paged=True, page_size=8,
+                     prefill_chunk=16)
+    assert got == want
+    print("init_model shardings OK")
     """)
 
 
@@ -316,7 +349,7 @@ def test_mesh_engine_argument_validation():
     from repro.serve import ServeEngine
     model = build_model(smoke_config("rwkv6-3b").replace(remat="none"))
     params = model.init(jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     eng = ServeEngine(model, params, max_slots=3, max_len=32, mesh=mesh)
     eng.close()
     with pytest.raises(ValueError, match="not both"):
@@ -324,4 +357,4 @@ def test_mesh_engine_argument_validation():
                     rules=object())
     with pytest.raises(ValueError, match="'model' axis"):
         ServeEngine(model, params, max_slots=2, max_len=32,
-                    mesh=jax.make_mesh((1,), ("data",)))
+                    mesh=make_mesh((1,), ("data",)))
