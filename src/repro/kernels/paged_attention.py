@@ -181,6 +181,7 @@ def paged_attention_mq(q, k_pages, v_pages, tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, W * G, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(tables, lengths, *operands)
     return (out.reshape(B, Hkv, W, G, D).transpose(0, 2, 1, 3, 4)
             .reshape(B, W, Hq, D))

@@ -385,32 +385,35 @@ def _paged_block(p, x, cfg, rules, *, positions, kv, tables,
     where ``moe_stats`` is the per-expert token/drop telemetry (zeros for
     dense blocks).
     """
-    h = L.rmsnorm(p["ln1"], x, use_pallas=cfg.use_pallas)
-    q, k, v = A.qkv_project(p["attn"], h, cfg, positions, rules=rules)
-    kv = write(kv, k, v)
-    o = A.paged_window_attention(q, kv["k"], kv["v"], tables, q_offset,
-                                 k_scale=kv.get("k_scale"),
-                                 v_scale=kv.get("v_scale"),
-                                 use_pallas=use_pallas)
-    x = x + comm.all_reduce_sum(A.out_project(p["attn"], o))
+    with jax.named_scope("attention"):
+        h = L.rmsnorm(p["ln1"], x, use_pallas=cfg.use_pallas)
+        q, k, v = A.qkv_project(p["attn"], h, cfg, positions, rules=rules)
+        kv = write(kv, k, v)
+        o = A.paged_window_attention(q, kv["k"], kv["v"], tables, q_offset,
+                                     k_scale=kv.get("k_scale"),
+                                     v_scale=kv.get("v_scale"),
+                                     use_pallas=use_pallas)
+        x = x + comm.all_reduce_sum(A.out_project(p["attn"], o))
 
-    h = L.rmsnorm(p["ln2"], x, use_pallas=cfg.use_pallas)
-    moe_stats = M.empty_expert_stats(cfg.n_experts)
-    if cfg.n_experts:
-        if comm.axis is None and ep_comm is None and rules is not None:
-            # training-style rules path: moe_apply owns its own shard_map
-            y, _ = M.moe_apply(p["moe"], h, cfg, rules)
+    with jax.named_scope("mlp"):
+        h = L.rmsnorm(p["ln2"], x, use_pallas=cfg.use_pallas)
+        moe_stats = M.empty_expert_stats(cfg.n_experts)
+        if cfg.n_experts:
+            if comm.axis is None and ep_comm is None and rules is not None:
+                # training-style rules path: moe_apply owns its own
+                # shard_map
+                y, _ = M.moe_apply(p["moe"], h, cfg, rules)
+            else:
+                # serving: expert-sharded (ep axis) and/or GEMM-sharded (tp
+                # axis), replicated activations; output already combined
+                y, _, moe_stats = M.moe_apply_expert_parallel(
+                    p["moe"], h, cfg, _SERIAL if ep_comm is None else ep_comm,
+                    shard_comm=comm if comm.axis is not None else None,
+                    placement=placement)
+            if cfg.dense_residual:
+                y = y + comm.all_reduce_sum(L.mlp(p["mlp"], h))
         else:
-            # serving: expert-sharded (ep axis) and/or GEMM-sharded (tp
-            # axis), replicated activations; output already combined
-            y, _, moe_stats = M.moe_apply_expert_parallel(
-                p["moe"], h, cfg, _SERIAL if ep_comm is None else ep_comm,
-                shard_comm=comm if comm.axis is not None else None,
-                placement=placement)
-        if cfg.dense_residual:
-            y = y + comm.all_reduce_sum(L.mlp(p["mlp"], h))
-    else:
-        y = comm.all_reduce_sum(L.mlp(p["mlp"], h))
+            y = comm.all_reduce_sum(L.mlp(p["mlp"], h))
     return x + y, kv, moe_stats
 
 
@@ -526,9 +529,10 @@ def paged_decode_step(params, cfg, rules, storage, tables, lengths, tokens,
     z = jnp.zeros((cfg.n_experts,), jnp.int32)
     (x, tok, drp), storage = jax.lax.scan(body, (x, z, z),
                                           (params["blocks"], storage))
-    x = L.rmsnorm(params["final_norm"], x, use_pallas=cfg.use_pallas)
-    logits = comm.all_gather(lm_logits(params, x, cfg, rules),
-                             axis=-1, tiled=True)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, use_pallas=cfg.use_pallas)
+        logits = comm.all_gather(lm_logits(params, x, cfg, rules),
+                                 axis=-1, tiled=True)
     return storage, logits, {"expert_tokens": tok, "expert_dropped": drp}
 
 
@@ -591,9 +595,10 @@ def paged_verify_chunk(params, cfg, rules, storage, tables, lengths, tokens,
     z = jnp.zeros((cfg.n_experts,), jnp.int32)
     (x, tok, drp), storage = jax.lax.scan(body, (x, z, z),
                                           (params["blocks"], storage))
-    x = L.rmsnorm(params["final_norm"], x, use_pallas=cfg.use_pallas)
-    logits = comm.all_gather(lm_logits(params, x, cfg, rules),
-                             axis=-1, tiled=True)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, use_pallas=cfg.use_pallas)
+        logits = comm.all_gather(lm_logits(params, x, cfg, rules),
+                                 axis=-1, tiled=True)
     return storage, logits, {"expert_tokens": tok, "expert_dropped": drp}
 
 

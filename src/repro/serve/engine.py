@@ -108,6 +108,23 @@ class Request:
     error: Optional[BaseException] = None  # set if prefill failed
 
 
+def _jit(name: str, fn, *, donate=(), mesh=None, in_specs=None,
+         out_specs=None):
+    """``jax.jit`` of ``fn`` as the program ``jit_<name>``: the name a
+    profiler trace gives its module and its ops.  With a ``mesh`` the body
+    runs under ``shard_map`` with the given specs."""
+    fn.__name__ = fn.__qualname__ = name
+    if mesh is not None:
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
+    return jax.jit(fn, donate_argnums=donate)
+
+
+# host spans (``serve.*``) in the profiler's trace, on the device trace's
+# clock; well under a microsecond each while no trace is being taken
+_span = jax.profiler.TraceAnnotation
+
+
 def _bucket(n: int, buckets=(32, 64, 128, 256, 512, 1024, 2048, 4096)) -> int:
     for b in buckets:
         if n <= b:
@@ -393,15 +410,17 @@ class ServeEngine:
                 else SP.make_drafter(spec_decode, model=model, params=params)
         # the per-position argmax the greedy acceptance rule scores against
         # (jitted: it runs on every verify tick)
-        self._verify_argmax = jax.jit(functools.partial(
-            greedy, true_vocab=model.cfg.vocab))
+        self._verify_argmax = _jit(
+            "verify_argmax",
+            lambda logits: greedy(logits, true_vocab=model.cfg.vocab))
         # jitted logits head so weight dequant-on-apply also covers the
         # host-driven prefill tail (identity deq when weights are float)
-        self._lm_head = jax.jit(
-            lambda p, h: model.lm_head(deq(p), h, rules))
+        self._lm_head = _jit(
+            "lm_head", lambda p, h: model.lm_head(deq(p), h, rules))
 
         self.last_token = np.zeros(max_slots, np.int32)
         self.finished: list[Request] = []
+        self._ticks_run = 0         # the serve.tick span's number
         self._rid = itertools.count()
         self._key = jax.random.PRNGKey(0)
         self.stats = {"ticks": 0, "tokens": 0, "prefills": 0,
@@ -432,46 +451,52 @@ class ServeEngine:
                 self.pool = PagePool(model.paged_leaf_specs(kvq),
                                      num_pages=num_pages, page_size=page_size,
                                      prefix_cache=self.prefix_cache)
-                self._cow_copy = jax.jit(
+                self._cow_copy = _jit(
+                    "cow_copy",
                     lambda st, s, d: PG.copy_pages(st, self.pool.leaf_specs,
                                                    s, d),
-                    donate_argnums=(0,))
-                self._decode_paged = jax.jit(
+                    donate=(0,))
+                self._decode_paged = _jit(
+                    "decode_paged",
                     lambda p, st, tb, ln, t, wp, wo, pl:
                     model.paged_decode_step(
                         deq(p), st, tb, ln, t, wp, wo, rules,
                         use_pallas=use_pallas_attention, quant=kvq,
                         placement=pl),
-                    donate_argnums=donate)
-                self._prefill_chunk = jax.jit(
+                    donate=donate)
+                self._prefill_chunk = _jit(
+                    "prefill_chunk",
                     lambda p, st, row, pg, s0, t, pl:
                     model.paged_prefill_chunk(
                         deq(p), st, row, pg, s0, t, rules,
                         use_pallas=use_pallas_attention, quant=kvq,
                         placement=pl),
-                    donate_argnums=donate)
-                self._verify_paged = jax.jit(
+                    donate=donate)
+                self._verify_paged = _jit(
+                    "verify_paged",
                     lambda p, st, tb, ln, t, wp, wo, pl: model.paged_verify(
                         deq(p), st, tb, ln, t, wp, wo, rules,
                         use_pallas=use_pallas_attention, quant=kvq,
                         placement=pl),
-                    donate_argnums=donate)
+                    donate=donate)
                 if self._n_image:
                     # separate jit so the embeds-free path stays byte-
                     # identical to the text-only engine (same program)
-                    self._prefill_chunk_embeds = jax.jit(
+                    self._prefill_chunk_embeds = _jit(
+                        "prefill_chunk_embeds",
                         lambda p, st, row, pg, s0, t, em, pl:
                         model.paged_prefill_chunk(
                             deq(p), st, row, pg, s0, t, rules,
                             use_pallas=use_pallas_attention, quant=kvq,
                             placement=pl, embeds=em),
-                        donate_argnums=donate)
+                        donate=donate)
                 if self._enc_dec:
                     # cross storage is READ-ONLY in these calls and not
                     # returned, so it must NOT be donated (donation would
                     # delete the live buffers); only the self-KV storage
                     # (argnum 1) is donated as usual
-                    self._decode_paged = jax.jit(
+                    self._decode_paged = _jit(
+                        "decode_paged",
                         lambda p, st, tb, ln, t, wp, wo, pl, cst, ctb, fl:
                         model.paged_decode_step(
                             deq(p), st, tb, ln, t, wp, wo, rules,
@@ -479,8 +504,9 @@ class ServeEngine:
                             placement=pl,
                             cross=dict(storage=cst, tables=ctb,
                                        frames_len=fl)),
-                        donate_argnums=donate)
-                    self._prefill_chunk = jax.jit(
+                        donate=donate)
+                    self._prefill_chunk = _jit(
+                        "prefill_chunk",
                         lambda p, st, row, pg, s0, t, pl, cst, ctb, fl:
                         model.paged_prefill_chunk(
                             deq(p), st, row, pg, s0, t, rules,
@@ -488,8 +514,9 @@ class ServeEngine:
                             placement=pl,
                             cross=dict(storage=cst, tables=ctb,
                                        frames_len=fl)),
-                        donate_argnums=donate)
-                    self._verify_paged = jax.jit(
+                        donate=donate)
+                    self._verify_paged = _jit(
+                        "verify_paged",
                         lambda p, st, tb, ln, t, wp, wo, pl, cst, ctb, fl:
                         model.paged_verify(
                             deq(p), st, tb, ln, t, wp, wo, rules,
@@ -497,7 +524,7 @@ class ServeEngine:
                             placement=pl,
                             cross=dict(storage=cst, tables=ctb,
                                        frames_len=fl)),
-                        donate_argnums=donate)
+                        donate=donate)
             else:
                 sspecs = model.paged_storage_specs(kvq)
                 self.pool = PagePool(
@@ -512,57 +539,57 @@ class ServeEngine:
                     else None
                 # COW copies move whole pages along the (replicated) page
                 # axis — each shard copies its local heads independently
-                self._cow_copy = jax.jit(jax.shard_map(
+                self._cow_copy = _jit(
+                    "cow_copy",
                     lambda st, s, d: PG.copy_pages(st, self.pool.leaf_specs,
                                                    s, d),
-                    mesh=mesh, in_specs=(sspecs, rep, rep),
-                    out_specs=sspecs, check_vma=False),
-                    donate_argnums=(0,))
-                self._decode_paged = jax.jit(jax.shard_map(
+                    donate=(0,), mesh=mesh, in_specs=(sspecs, rep, rep),
+                    out_specs=sspecs)
+                self._decode_paged = _jit(
+                    "decode_paged",
                     lambda p, st, tb, ln, t, wp, wo, pl:
                     model.paged_decode_step(
                         deq(p), st, tb, ln, t, wp, wo, None,
                         use_pallas=use_pallas_attention, comm=comm,
                         quant=kvq, ep_comm=ep_comm, placement=pl),
-                    mesh=mesh,
+                    donate=donate, mesh=mesh,
                     in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep, rep),
-                    out_specs=(sspecs, rep, rep), check_vma=False),
-                    donate_argnums=donate)
-                self._prefill_chunk = jax.jit(jax.shard_map(
+                    out_specs=(sspecs, rep, rep))
+                self._prefill_chunk = _jit(
+                    "prefill_chunk",
                     lambda p, st, row, pg, s0, t, pl:
                     model.paged_prefill_chunk(
                         deq(p), st, row, pg, s0, t, None,
                         use_pallas=use_pallas_attention, comm=comm,
                         quant=kvq, ep_comm=ep_comm, placement=pl),
-                    mesh=mesh,
+                    donate=donate, mesh=mesh,
                     in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep),
-                    out_specs=(sspecs, rep, rep), check_vma=False),
-                    donate_argnums=donate)
-                self._verify_paged = jax.jit(jax.shard_map(
+                    out_specs=(sspecs, rep, rep))
+                self._verify_paged = _jit(
+                    "verify_paged",
                     lambda p, st, tb, ln, t, wp, wo, pl: model.paged_verify(
                         deq(p), st, tb, ln, t, wp, wo, None,
                         use_pallas=use_pallas_attention, comm=comm,
                         quant=kvq, ep_comm=ep_comm, placement=pl),
-                    mesh=mesh,
+                    donate=donate, mesh=mesh,
                     in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep, rep),
-                    out_specs=(sspecs, rep, rep), check_vma=False),
-                    donate_argnums=donate)
+                    out_specs=(sspecs, rep, rep))
                 if self._n_image:
                     # image embeds replicate like the token chunk (the
                     # prefix rides the replicated activation path; heads
                     # shard inside the model as usual)
-                    self._prefill_chunk_embeds = jax.jit(jax.shard_map(
+                    self._prefill_chunk_embeds = _jit(
+                        "prefill_chunk_embeds",
                         lambda p, st, row, pg, s0, t, em, pl:
                         model.paged_prefill_chunk(
                             deq(p), st, row, pg, s0, t, None,
                             use_pallas=use_pallas_attention, comm=comm,
                             quant=kvq, ep_comm=ep_comm, placement=pl,
                             embeds=em),
-                        mesh=mesh,
+                        donate=donate, mesh=mesh,
                         in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep,
                                   rep),
-                        out_specs=(sspecs, rep, rep), check_vma=False),
-                        donate_argnums=donate)
+                        out_specs=(sspecs, rep, rep))
             cross_kw = {}
             if self._enc_dec:
                 # one read-only cross-KV pool sized for every slot holding
@@ -578,14 +605,16 @@ class ServeEngine:
                 # state), farmed over the ThreadFarmExecutor like dense
                 # prefills; the scatter into pool pages is applied
                 # serially afterwards (cross storage donated HERE only)
-                self._encode_chunk = jax.jit(
+                self._encode_chunk = _jit(
+                    "encode_chunk",
                     lambda p, fr, s0, nv: model.cross_kv_chunk(
                         deq(p),
                         model.encode_chunk(deq(p), fr, s0, nv, rules)))
-                self._scatter_cross = jax.jit(
+                self._scatter_cross = _jit(
+                    "scatter_cross",
                     lambda st, pg, k, v: model.scatter_cross(
                         st, pg, k, v, page_size=page_size, quant=kvq),
-                    donate_argnums=(0,))
+                    donate=(0,))
             self.sched = Scheduler(max_slots=max_slots, max_len=max_len,
                                    pool=self.pool,
                                    prefill_chunk=prefill_chunk,
@@ -598,10 +627,11 @@ class ServeEngine:
                 self._fresh_state = lambda: model.init_decode_state(
                     max_slots, max_len)
                 self.state = self._fresh_state()
-                self._decode = jax.jit(
+                self._decode = _jit(
+                    "decode",
                     lambda p, s, t, pos: model.decode_step(p, s, t, pos,
                                                            rules),
-                    donate_argnums=donate)
+                    donate=donate)
             else:
                 if max_slots % self.tp:
                     raise ValueError(
@@ -614,16 +644,15 @@ class ServeEngine:
                 self._fresh_state = lambda: jax.device_put(
                     model.init_decode_state(max_slots, max_len), st_sh)
                 self.state = self._fresh_state()
-                self._decode = jax.jit(jax.shard_map(
+                self._decode = _jit(
+                    "decode",
                     lambda p, s, t, pos: model.decode_step(p, s, t, pos,
                                                            None),
-                    mesh=mesh,
+                    donate=donate, mesh=mesh,
                     in_specs=(pspecs, st_specs, P("model", None), P("model")),
-                    out_specs=(st_specs, P("model", None, None)),
-                    check_vma=False),
-                    donate_argnums=donate)
-            self._prefill = jax.jit(
-                lambda p, b: model.prefill(p, b, rules, max_len))
+                    out_specs=(st_specs, P("model", None, None)))
+            self._prefill = _jit(
+                "prefill", lambda p, b: model.prefill(p, b, rules, max_len))
 
     # -- compat views --------------------------------------------------------
 
@@ -875,12 +904,12 @@ class ServeEngine:
             return f"prompt length {len(r.prompt)} >= max_len {self.max_len}"
         return [(r, ValueError(why(r))) for r in rejects]
 
-    def _commit_decode(self, live, logits) -> list:
-        """Sample + book one decoded token for every live slot.  Slots
-        whose per-request sampler raised are retired instead (their pages
-        return to the pool); returns their (req, error) pairs."""
+    def _commit_decode(self, live, nxt, sample_errors) -> list:
+        """Book one decoded token (``_sample_batch``'s draw) for every live
+        slot.  Slots whose per-request sampler raised are retired instead
+        (their pages return to the pool); returns their (req, error)
+        pairs."""
         self.stats["ticks"] += 1
-        nxt, sample_errors = self._sample_batch(logits[:, -1], live)
         bad = {slot for slot, _ in sample_errors}
         for slot in live:
             if slot in bad:
@@ -955,11 +984,12 @@ class ServeEngine:
     # -- paged tick ----------------------------------------------------------
 
     def _tick_paged(self) -> bool:
-        _, rejects = self.sched.admit()
+        with _span("serve.schedule"):
+            _, rejects = self.sched.admit()
+            jobs = self.sched.next_chunks()
         errors = self._reject_errors(rejects)
 
         failed = set()
-        jobs = self.sched.next_chunks()
         enc_jobs = [j for j in jobs if isinstance(j, EncodeJob)]
         jobs = [j for j in jobs if not isinstance(j, EncodeJob)]
         if enc_jobs:
@@ -1004,45 +1034,47 @@ class ServeEngine:
             # mid-chunked-prefill — in the device call, the lm head or its
             # own sampler — must hand every reserved page back to the pool
             # (release) instead of aborting the tick holding them
-            try:
-                if job.embeds is not None:
-                    storage, hidden, tel = self._prefill_chunk_embeds(
-                        self.params, self.pool.storage,
-                        jnp.asarray(self.sched.table[job.slot]),
-                        jnp.asarray(job.pages), np.int32(job.start),
-                        jnp.asarray(job.tokens[None]),
-                        jnp.asarray(job.embeds[None]), self._place_arr)
-                elif self._enc_dec:
-                    storage, hidden, tel = self._prefill_chunk(
-                        self.params, self.pool.storage,
-                        jnp.asarray(self.sched.table[job.slot]),
-                        jnp.asarray(job.pages), np.int32(job.start),
-                        jnp.asarray(job.tokens[None]), self._place_arr,
-                        self.cross_pool.storage,
-                        jnp.asarray(self.sched.cross_table[job.slot]),
-                        np.int32(self.sched.enc_total[job.slot]))
-                else:
-                    storage, hidden, tel = self._prefill_chunk(
-                        self.params, self.pool.storage,
-                        jnp.asarray(self.sched.table[job.slot]),
-                        jnp.asarray(job.pages), np.int32(job.start),
-                        jnp.asarray(job.tokens[None]), self._place_arr)
-                self.pool.storage = storage
-                self._account_moe(tel)
-                self.sched.chunk_done(job)
-                self.stats["chunk_prefills"] += 1
+            with _span("serve.prefill", rid=job.req.rid, start=job.start,
+                       n=job.n_valid):
+                try:
+                    if job.embeds is not None:
+                        storage, hidden, tel = self._prefill_chunk_embeds(
+                            self.params, self.pool.storage,
+                            jnp.asarray(self.sched.table[job.slot]),
+                            jnp.asarray(job.pages), np.int32(job.start),
+                            jnp.asarray(job.tokens[None]),
+                            jnp.asarray(job.embeds[None]), self._place_arr)
+                    elif self._enc_dec:
+                        storage, hidden, tel = self._prefill_chunk(
+                            self.params, self.pool.storage,
+                            jnp.asarray(self.sched.table[job.slot]),
+                            jnp.asarray(job.pages), np.int32(job.start),
+                            jnp.asarray(job.tokens[None]), self._place_arr,
+                            self.cross_pool.storage,
+                            jnp.asarray(self.sched.cross_table[job.slot]),
+                            np.int32(self.sched.enc_total[job.slot]))
+                    else:
+                        storage, hidden, tel = self._prefill_chunk(
+                            self.params, self.pool.storage,
+                            jnp.asarray(self.sched.table[job.slot]),
+                            jnp.asarray(job.pages), np.int32(job.start),
+                            jnp.asarray(job.tokens[None]), self._place_arr)
+                    self.pool.storage = storage
+                    self._account_moe(tel)
+                    self.sched.chunk_done(job)
+                    self.stats["chunk_prefills"] += 1
+                    if job.is_last:
+                        i = job.n_valid - 1
+                        logits = self._lm_head(self.params, hidden[:, i:i + 1])
+                        tok = self._sample_one(job.req, logits[0, -1])
+                except BaseException as e:                      # noqa: BLE001
+                    failed.add(job.slot)
+                    self.sched.release(job.slot)
+                    errors.append((job.req, e))
+                    self._recover_donated_storage()
+                    continue
                 if job.is_last:
-                    i = job.n_valid - 1
-                    logits = self._lm_head(self.params, hidden[:, i:i + 1])
-                    tok = self._sample_one(job.req, logits[0, -1])
-            except BaseException as e:                      # noqa: BLE001
-                failed.add(job.slot)
-                self.sched.release(job.slot)
-                errors.append((job.req, e))
-                self._recover_donated_storage()
-                continue
-            if job.is_last:
-                self._emit_first_token(job.slot, tok)
+                    self._emit_first_token(job.slot, tok)
 
         live = self.sched.live_slots()
         cow = []
@@ -1050,84 +1082,54 @@ class ServeEngine:
         if live and self.drafter is not None:
             drafts = self._propose_drafts(live)
         if live:
-            # may preempt the youngest and/or schedule copy-on-write moves;
-            # draft windows reserve their extra write pages best-effort
-            # (never preempting — speculation can't evict anyone)
-            _, cow, granted = self.sched.ensure_decode_pages(
-                extra={s: len(d) for s, d in drafts.items()} or None)
-            drafts = {s: d[:granted.get(s, 0)]
-                      for s, d in drafts.items()
-                      if self.sched.slot_req[s] is not None
-                      and granted.get(s, 0) > 0}
-            live = self.sched.live_slots()
-            # a COW'd slot preempted later in the same pass already gave
-            # its copy page back — don't write into it
-            cow = [(s, a, b) for s, a, b in cow
-                   if self.sched.slot_req[s] is not None]
+            with _span("serve.pages"):
+                # may preempt the youngest and/or schedule copy-on-write
+                # moves; draft windows reserve their extra write pages
+                # best-effort (never preempting — speculation can't evict
+                # anyone)
+                _, cow, granted = self.sched.ensure_decode_pages(
+                    extra={s: len(d) for s, d in drafts.items()} or None)
+                drafts = {s: d[:granted.get(s, 0)]
+                          for s, d in drafts.items()
+                          if self.sched.slot_req[s] is not None
+                          and granted.get(s, 0) > 0}
+                live = self.sched.live_slots()
+                # a COW'd slot preempted later in the same pass already
+                # gave its copy page back — don't write into it
+                cow = [(s, a, b) for s, a, b in cow
+                       if self.sched.slot_req[s] is not None]
         self.stats["preemptions"] = self.sched.preemptions
         if live:
-            ps = self.pool.page_size
-            B = self.max_slots
-            # verify width: the widest granted draft + 1, bucketed to two
-            # compile shapes (half / full window) so a tick whose drafts
-            # are short doesn't pay the full spec_k+1-wide forward
-            C = self._spec_width(max(len(d) for d in drafts.values())
-                                 + 1) if drafts else 1
-            wpages = np.full((B, C), self.pool.trash_page, np.int32)
-            woffs = np.zeros((B, C), np.int32)
-            lens = np.zeros(B, np.int32)
-            toks = np.zeros((B, C), np.int32)
-            for slot in live:
-                ln = int(self.sched.lengths[slot])
-                lens[slot] = ln
-                toks[slot, 0] = self.last_token[slot]
-                d = drafts.get(slot)
-                nd = 0 if d is None else len(d)
-                if nd:
-                    toks[slot, 1:1 + nd] = d
-                for i in range(nd + 1):
-                    wpages[slot, i] = self.sched.table[slot, (ln + i) // ps]
-                    woffs[slot, i] = (ln + i) % ps
             # sampled speculation (spec_temperature > 0) must route EVERY
             # tick through the verify commit — otherwise no-draft ticks
             # would fall back to the engine's greedy sampler and the
             # stream would mix greedy and temperature-sampled tokens
-            spec_sampled = self.drafter is not None and \
-                self.spec_temperature > 0
-            cross_args = ()
-            if self._enc_dec:
-                # dead slots keep frames_len=0: every cross read is fully
-                # masked (attention renormalizes to zeros), so their stale
-                # table rows are never observable
-                cflens = np.zeros(B, np.int32)
-                for slot in live:
-                    cflens[slot] = self.sched.enc_total[slot]
-                cross_args = (self.cross_pool.storage,
-                              jnp.asarray(self.sched.cross_table),
-                              jnp.asarray(cflens))
+            verify = bool(drafts) or (self.drafter is not None
+                                      and self.spec_temperature > 0)
+            with _span("serve.upload"):
+                args, cow_args = self._step_inputs(live, drafts, cow,
+                                                   verify)
             try:
-                if cow:         # copies strictly before this tick's writes
-                    self.pool.storage = self._cow_copy(
-                        self.pool.storage,
-                        jnp.asarray([a for _, a, _ in cow], jnp.int32),
-                        jnp.asarray([b for _, _, b in cow], jnp.int32))
-                if drafts or spec_sampled:
-                    self.pool.storage, logits, tel = self._verify_paged(
-                        self.params, self.pool.storage,
-                        jnp.asarray(self.sched.table), jnp.asarray(lens),
-                        jnp.asarray(toks), jnp.asarray(wpages),
-                        jnp.asarray(woffs), self._place_arr, *cross_args)
+                with _span("serve.step"):
+                    if cow:     # copies strictly before this tick's writes
+                        self.pool.storage = self._cow_copy(
+                            self.pool.storage, *cow_args)
+                    step = self._verify_paged if verify \
+                        else self._decode_paged
+                    self.pool.storage, logits, tel = step(
+                        self.params, self.pool.storage, *args)
+                with _span("serve.sample"):
                     self._account_moe(tel)
-                    errors += self._commit_verify(live, drafts, logits)
-                else:
-                    self.pool.storage, logits, tel = self._decode_paged(
-                        self.params, self.pool.storage,
-                        jnp.asarray(self.sched.table), jnp.asarray(lens),
-                        jnp.asarray(toks), jnp.asarray(wpages[:, 0]),
-                        jnp.asarray(woffs[:, 0]), self._place_arr,
-                        *cross_args)
-                    self._account_moe(tel)
-                    errors += self._commit_decode(live, logits)
+                    if verify:
+                        picked = self._verify_picks(logits)
+                    else:
+                        picked = self._sample_batch(logits[:, -1], live)
+                with _span("serve.commit"):
+                    if verify:
+                        errors += self._commit_verify(live, drafts, logits,
+                                                      *picked)
+                    else:
+                        errors += self._commit_decode(live, *picked)
             except BaseException:
                 # a decode/commit failure still raises (engine-level, not
                 # one request's fault) — but first un-brick the engine if
@@ -1155,6 +1157,52 @@ class ServeEngine:
             self.update_placement()
         self._raise_or_record(errors)
         return bool(live) or self.sched.has_work()
+
+    def _step_inputs(self, live, drafts, cow, verify: bool):
+        """The device arrays of one decode (or verify) step after the
+        params and storage, and of its copy-on-write moves: every slot's
+        page table, length, input tokens and write targets (dead slots
+        write to the trash page)."""
+        ps = self.pool.page_size
+        B = self.max_slots
+        # verify width: the widest granted draft + 1, bucketed to two
+        # compile shapes (half / full window) so a tick whose drafts
+        # are short doesn't pay the full spec_k+1-wide forward
+        C = self._spec_width(max(len(d) for d in drafts.values())
+                             + 1) if drafts else 1
+        wpages = np.full((B, C), self.pool.trash_page, np.int32)
+        woffs = np.zeros((B, C), np.int32)
+        lens = np.zeros(B, np.int32)
+        toks = np.zeros((B, C), np.int32)
+        for slot in live:
+            ln = int(self.sched.lengths[slot])
+            lens[slot] = ln
+            toks[slot, 0] = self.last_token[slot]
+            d = drafts.get(slot)
+            nd = 0 if d is None else len(d)
+            if nd:
+                toks[slot, 1:1 + nd] = d
+            for i in range(nd + 1):
+                wpages[slot, i] = self.sched.table[slot, (ln + i) // ps]
+                woffs[slot, i] = (ln + i) % ps
+        if not verify:
+            wpages, woffs = wpages[:, 0], woffs[:, 0]
+        args = (jnp.asarray(self.sched.table), jnp.asarray(lens),
+                jnp.asarray(toks), jnp.asarray(wpages), jnp.asarray(woffs),
+                self._place_arr)
+        if self._enc_dec:
+            # dead slots keep frames_len=0: every cross read is fully
+            # masked (attention renormalizes to zeros), so their stale
+            # table rows are never observable
+            cflens = np.zeros(B, np.int32)
+            for slot in live:
+                cflens[slot] = self.sched.enc_total[slot]
+            args += (self.cross_pool.storage,
+                     jnp.asarray(self.sched.cross_table), jnp.asarray(cflens))
+        cow_args = (jnp.asarray([a for _, a, _ in cow], jnp.int32),
+                    jnp.asarray([b for _, _, b in cow], jnp.int32)) \
+            if cow else ()
+        return args, cow_args
 
     # -- expert telemetry + load-aware placement -----------------------------
 
@@ -1237,21 +1285,26 @@ class ServeEngine:
                 drafts[slot] = prop[:budget]
         return drafts
 
-    def _commit_verify(self, live, drafts, logits) -> list:
+    def _verify_picks(self, logits):
+        """A verify forward's result on the host: the per-position argmax
+        for greedy acceptance, or the logits and this tick's key for
+        rejection sampling (which never reads the argmax)."""
+        if self.spec_temperature <= 0:
+            return np.array(jax.device_get(self._verify_argmax(logits))), None
+        self._key, tick_key = jax.random.split(self._key)
+        return np.asarray(jax.device_get(logits)), tick_key
+
+    def _commit_verify(self, live, drafts, logits, host, tick_key) -> list:
         """Book a verify forward's emitted tokens for every live slot:
         the accepted draft prefix + one correction/bonus each (a slot
-        without drafts emits exactly its plain decoded token).  Per-token
+        without drafts emits exactly its plain decoded token).  ``host``
+        and ``tick_key`` are :meth:`_verify_picks`'s.  Per-token
         bookkeeping mirrors :meth:`_commit_decode`, so retirement (EOS /
         budget / max_len) happens at the same stream position speculation
         on or off; afterwards every surviving slot hands its
         over-reserved verify pages back to the pool."""
         self.stats["ticks"] += 1
         greedy_mode = self.spec_temperature <= 0
-        if greedy_mode:         # the rejection path never reads the argmax
-            rows = np.array(jax.device_get(self._verify_argmax(logits)))
-        else:
-            self._key, tick_key = jax.random.split(self._key)
-            logits_np = np.asarray(jax.device_get(logits))
         errors = []
         for slot in live:
             req = self.sched.slot_req[slot]
@@ -1279,11 +1332,11 @@ class ServeEngine:
                                                slot * (self.spec_k + 2) + i)
                             for i in range(nd + 1)]
                 accepted, emitted = spec_rejection_sample(
-                    keys, logits_np[slot, :nd + 1], draft,
+                    keys, host[slot, :nd + 1], draft,
                     temperature=self.spec_temperature,
                     true_vocab=self.model.cfg.vocab)
             else:
-                accepted, emitted = spec_verify_greedy(rows[slot], draft)
+                accepted, emitted = spec_verify_greedy(host[slot], draft)
             self.stats["draft_proposed"] += nd
             self.stats["draft_accepted"] += accepted
             for tok in emitted:
@@ -1354,9 +1407,13 @@ class ServeEngine:
             toks = jnp.asarray(self.last_token.reshape(-1, 1))
             pos = jnp.asarray(self.sched.lengths.astype(np.int32))
             try:
-                self.state, logits = self._decode(self.params, self.state,
-                                                  toks, pos)
-                errors += self._commit_decode(live, logits)
+                with _span("serve.step"):
+                    self.state, logits = self._decode(self.params,
+                                                      self.state, toks, pos)
+                with _span("serve.sample"):
+                    picked = self._sample_batch(logits[:, -1], live)
+                with _span("serve.commit"):
+                    errors += self._commit_decode(live, *picked)
             except BaseException:
                 self._recover_donated_state()
                 for req, err in errors:
@@ -1370,7 +1427,9 @@ class ServeEngine:
 
     def tick(self) -> bool:
         """One serving step; True while the engine still has work."""
-        return self._tick_paged() if self.paged else self._tick_dense()
+        self._ticks_run += 1
+        with _span("serve.tick", tick=self._ticks_run):
+            return self._tick_paged() if self.paged else self._tick_dense()
 
     def run_until_drained(self, max_ticks: int = 10_000):
         """Tick until idle; returns the finished requests."""
