@@ -48,7 +48,7 @@ def paged_attention(q, k_pages, v_pages, tables, lengths):
 def paged_attention_mq(q, k_pages, v_pages, tables, lengths,
                        k_scale=None, v_scale=None):
     # k_scale/v_scale: optional (N, page_size, Hkv) int8-page dequant
-    # scales, fused into the kernel's VMEM tile right after the page DMA
+    # scales, copied beside their pages and applied in VMEM
     return _pa.paged_attention_mq(q, k_pages, v_pages, tables, lengths,
                                   k_scale=k_scale, v_scale=v_scale,
                                   interpret=_interpret())

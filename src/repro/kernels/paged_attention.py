@@ -1,28 +1,35 @@
-"""Paged multi-query attention: gather K/V through a page table, on-chip.
+"""Paged multi-query attention: each slot's live pages, straight from HBM.
 
 A window of W queries per sequence attends to a KV prefix that lives in
 non-contiguous fixed-size pages (:mod:`repro.serve.pages`).  Instead of
 materializing the gathered (B, S, Hkv, D) cache in HBM — the jnp fallback in
-:mod:`repro.models.attention` — the kernel streams each sequence's pages
-HBM->VMEM directly via a scalar-prefetched page table: BlockSpec index maps
-read ``table[b, p]`` to pick the page, so the DMA engine performs the gather
-and the online-softmax state (acc, m, l) never leaves VMEM scratch.
+:mod:`repro.models.attention` — the kernel copies each slot's live pages
+HBM->VMEM itself and keeps the online-softmax state (acc, m, l) in VMEM
+scratch.  Its cost follows the slots' live lengths, not the table width P.
 
-Grid = (B, pages_per_seq) with pages innermost.  Each step DMAs one whole
-page (all KV heads) and loops over the heads statically: one
-(W*G, page_size) score tile per head (G = grouped q heads per KV head, W
+Grid = (B,), one step per slot.  Inside it a ``fori_loop`` runs over the
+slot's live blocks only: ``cdiv(lengths[b] + W - 1, block_tokens)`` of
+them, read from the scalar-prefetched ``lengths``, so a slot whose window
+reads nothing does no iterations.  A block is ``_block_pages`` whole pages
+(at least 128 tokens).  Its pages are brought in by one manual DMA per page
+and per leaf, from ``table[b, i]`` into a double buffer: block i+1's copies
+are in flight while block i computes.  A page past the window's reach is
+not copied.
+
+Per block the kernel loops over the KV heads statically: one
+(W*G, block_tokens) score tile per head (G = grouped q heads per KV head, W
 query rows stacked head-major so row r serves window position ``r // G``).
 The causal rule is per row: window position w may read KV positions
 ``< lengths[b] + w`` — ``lengths`` counts valid KV entries *including*
 window position 0's token (all W tokens' K/V must be written to their
-pages before the call).  Pages
-past the LAST row's limit are skipped with ``pl.when`` — cost is
-O(lengths + W), not O(pages_per_seq), which is the whole point of paging.
-Rows whose limit ends before a visited page contribute nothing (their
-probabilities are zeroed, not renormalized with exp(0)); dead slots
-(length 0) produce a zero row 0.
+pages before the call).  Masked scores become NEG_INF and masked
+probabilities are zeroed, not renormalized with exp(0), so a row whose
+limit ends before a visited block contributes nothing; V rows past the
+window's reach are zeroed too, so whatever a block's unread tail holds
+(stale VMEM, pages of other sequences) never reaches the output.  A slot
+with nothing to read (length 0) produces a zero row 0.
 
-W = 1 is the single-query decode kernel: same per-head math, page gate
+W = 1 is the single-query decode kernel: same per-head math, block loop
 and mask as every wider window.
 
 Tensor-parallel serving runs this kernel INSIDE a ``shard_map`` body: q and
@@ -41,71 +48,172 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 NEG_INF = -1e30
+BLOCK_TOKENS = 128          # a block's least width: one MXU tile of keys
+CHUNK_ROWS = 128            # query rows scored against a block at once
+# A wide window's call holds ~6 MB of VMEM as the v5e compiler counts it
+# for a described chip, and the chip's own count has read twice such a
+# figure: room above the 16 MB default scoped limit.
+VMEM_LIMIT = 32 * 2**20
 
 
-def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                     scale: float, page_size: int, n_heads: int,
-                     n_pages: int, window: int, group: int,
-                     quantized: bool = False):
+def _block_pages(page_size: int, n_table: int) -> int:
+    """Whole pages per block: at least BLOCK_TOKENS tokens, at most the
+    table."""
+    return max(1, min(-(-BLOCK_TOKENS // page_size), n_table))
+
+
+def _scale_row(buf, slot, block_pages: int, page_rows: int):
+    """A block's per-(token, head) scales as one (1, bt*Hkv) lane row:
+    the pages' rows side by side, each cut to its ``page_rows`` lanes."""
+    return jnp.concatenate([buf[slot, j][:, :page_rows]
+                            for j in range(block_pages)], axis=-1)
+
+
+def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
+                     scale: float, page_size: int, block_pages: int,
+                     n_heads: int, n_table: int, window: int, group: int,
+                     quantized: bool):
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
+         acc_ref, m_ref, l_ref) = rest
+        leaves = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                  (vs_hbm, vs_buf))
     else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+        o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref = rest
+        leaves = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
-    p = pl.program_id(1)
     length = len_ref[b]
+    # the deepest-reaching row (w = window-1) reads positions < reach
+    reach = length + window - 1
+    bt = block_pages * page_size
+    page_rows = page_size * n_heads         # a page's rows: (token, head)
+    rows = window * group                   # query rows of one KV head
+    n_blocks = jnp.minimum((reach + bt - 1) // bt,
+                           -(-n_table // block_pages))
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def copy_block(blk, slot, start: bool):
+        """Start (or wait for) the DMAs of block ``blk`` into buffer
+        ``slot``: one per page and leaf, skipping pages past the reach.
+        A loop, not unrolled code: each unrolled page would be traced
+        anew for every program that holds the kernel."""
+        def page_copies(j, carry):
+            idx = blk * block_pages + j
+            page = tbl_ref[b * n_table + jnp.minimum(idx, n_table - 1)]
+            row = pl.multiple_of(j * page_rows, page_rows)
 
-    # Skip pages no row can see: the deepest-reaching row (w = window-1)
-    # reads KV positions < length + window - 1.
-    @pl.when(p * page_size < length + window - 1)
-    def _compute():
-        # One DMA brings the whole page (every KV head); heads are static
-        # slices of that VMEM tile, so each page leaves HBM once.
-        for h in range(n_heads):
-            q = q_ref[0, h].astype(jnp.float32) * scale      # (W*G, D)
-            k = k_ref[0, :, h, :].astype(jnp.float32)        # (page_size, D)
-            v = v_ref[0, :, h, :].astype(jnp.float32)
-            if quantized:
-                # fused dequant: the int8 page tile was DMA'd HBM->VMEM (the
-                # bandwidth win) and the per-(row, head) scale is applied
-                # here in VMEM — the full-precision K/V never exists in HBM
-                k = k * ks_ref[0, :, h:h + 1]                # (page_size, 1)
-                v = v * vs_ref[0, :, h:h + 1]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+            @pl.when(jnp.logical_and(idx < n_table,
+                                     idx * page_size < reach))
+            def _():
+                for src, buf in leaves:
+                    # values land as rows of the block, scales as a page
+                    dst = (buf.at[slot, pl.ds(row, page_rows)]
+                           if buf.ndim == 3 else buf.at[slot, j])
+                    cp = pltpu.make_async_copy(src.at[page], dst,
+                                               sems.at[slot])
+                    if start:
+                        cp.start()
+                    else:
+                        cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, block_pages, page_copies, 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(n_blocks > 0)
+    def _prefetch_first():
+        copy_block(0, 0, start=True)
+
+    def body(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _prefetch_next():
+            copy_block(i + 1, 1 - slot, start=True)
+
+        copy_block(i, slot, start=False)
+
+        # Block rows are (token, head) pairs, row c = token c // n_heads of
+        # the block, KV head c % n_heads: the pool's own order, so a page
+        # lands in VMEM as one contiguous slab.
+        k = k_buf[slot].astype(q_ref.dtype)              # (bt*Hkv, D)
+        v = v_buf[slot].astype(q_ref.dtype)
+        # the block's rows as lanes of a score tile: head and position
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, bt * n_heads), 1)
+        col_head, col_tok = col % n_heads, i * bt + col // n_heads
+        # rows past the window's reach may hold anything (stale VMEM, a
+        # page never copied): zero them so 0 * garbage cannot reach acc
+        tok = i * bt + jax.lax.broadcasted_iota(
+            jnp.int32, (bt * n_heads, 1), 0) // n_heads
+        v = jnp.where(tok < reach, v, jnp.zeros_like(v))
+        if quantized:
+            # fused dequant: the int8 pages crossed HBM->VMEM (the
+            # bandwidth win); int8 values are exact in q's dtype, and the
+            # per-(token, head) scales, one lane per block row, multiply
+            # the scores (K) and the probabilities (V) in float32
+            ks = _scale_row(ks_buf, slot, block_pages, page_rows)
+            vs = _scale_row(vs_buf, slot, block_pages, page_rows)
+            vs = jnp.where(col_tok < reach, vs, 0.0)
+
+        # Query rows are (head, window position, group) — row r serves
+        # KV head r // rows and window position (r % rows) // group — and
+        # are scored in chunks of whole heads against every block row; a
+        # score counts only where the row's head is the column's.  A chunk
+        # is the most heads that divide Hkv and fit CHUNK_ROWS (one head
+        # at least).
+        per_chunk = max(d for d in range(1, n_heads + 1)
+                        if n_heads % d == 0
+                        and (d == 1 or d * rows <= CHUNK_ROWS))
+        n = per_chunk * rows
+
+        def attend(r0):
+            row = r0 + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+            valid = jnp.logical_and(
+                col_head == row // rows,
+                col_tok < length + (row % rows) // group)    # (n, bt*Hkv)
+            s = jax.lax.dot_general(q_ref[0, pl.ds(r0, n)], k,
+                                    (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-            k_pos = p * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            w_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-            valid = k_pos < length + w_row
+            s = s * (ks * scale) if quantized else s * scale
             s = jnp.where(valid, s, NEG_INF)
 
-            m_prev = m_ref[h]                                # (W*G, 1)
+            m_prev = m_ref[pl.ds(r0, n)]                 # (n, 1)
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             pr = jnp.exp(s - m_new)
-            # A row may be fully masked on a visited page (its limit ends on
-            # an earlier page): m_new stays NEG_INF and exp(s - m_new) would
-            # be exp(0) = 1.  Zero masked probabilities explicitly — a
-            # bitwise no-op for live rows, where exp(NEG_INF - finite)
+            # A row may be fully masked in a visited block (its limit ends
+            # in an earlier one): m_new stays NEG_INF and exp(s - m_new)
+            # would be exp(0) = 1.  Zero masked probabilities explicitly —
+            # a bitwise no-op for live rows, where exp(NEG_INF - finite)
             # underflows to 0.
             pr = jnp.where(valid, pr, 0.0)
             corr = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_ref[h] * corr + pr.sum(axis=-1, keepdims=True)
-            acc_ref[h] = (acc_ref[h] * corr
-                          + jax.lax.dot_general(
-                              pr, v, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32))
-            m_ref[h] = m_new
+            l_ref[pl.ds(r0, n)] = (l_ref[pl.ds(r0, n)] * corr
+                                   + pr.sum(axis=-1, keepdims=True))
+            pv = pr * vs if quantized else pr
+            acc_ref[pl.ds(r0, n)] = (
+                acc_ref[pl.ds(r0, n)] * corr
+                + jax.lax.dot_general(pv.astype(v.dtype), v,
+                                      (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32))
+            m_ref[pl.ds(r0, n)] = m_new
 
-    @pl.when(p == n_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        n_chunks = n_heads // per_chunk
+        if n_chunks > 1 and n % 16 == 0:
+            # a loop, not unrolled code: one chunk's temporaries in VMEM
+            def chunk(j, carry):
+                attend(pl.multiple_of(j * n, 16))
+                return carry
+            jax.lax.fori_loop(0, n_chunks, chunk, 0)
+        else:
+            for j in range(n_chunks):
+                attend(j * n)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, body, 0)
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention_mq(q, k_pages, v_pages, tables, lengths, *,
@@ -119,67 +227,94 @@ def paged_attention_mq(q, k_pages, v_pages, tables, lengths, *,
     offset); rows past a sequence's data (pad rows, dead slots) are never
     read by callers and may hold garbage softmaxed over trash pages.
 
+    Scores and P·V run on the MXU in q's dtype with float32 accumulation;
+    the softmax state is float32.
+
     ``k_scale``/``v_scale``: optional (N, page_size, Hkv) per-(row, head)
-    dequantization scales for int8 pages.  They ride the same
-    scalar-prefetched page-table index map as their value pages and are
-    applied to the K/V tile in VMEM right after the DMA — the page stream
-    out of HBM stays int8, which is where the 4x bandwidth cut happens.
+    dequantization scales for int8 pages.  Each page's scales are copied
+    beside its values and applied in VMEM, to the scores (K) and to the
+    probabilities (V) — the page stream out of HBM stays int8, which is
+    where the 4x bandwidth cut happens.
     """
+    D = q.shape[-1]
+    assert q.shape[2] % k_pages.shape[2] == 0, (q.shape, k_pages.shape)
+    assert (k_scale is None) == (v_scale is None), "pass both scales or none"
+    if D % 128 == 0:
+        return _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale,
+                         v_scale, scale=D ** -0.5, interpret=interpret)
+    # the TPU copies only whole 128-lane rows out of HBM: zero-pad the head
+    # dim (a copy of the pool, for heads narrower than a lane tile only;
+    # zeros change no dot product)
+    pad = ((0, 0),) * 3 + ((0, -D % 128),)
+    q, k_pages, v_pages = (jnp.pad(x, pad) for x in (q, k_pages, v_pages))
+    return _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale,
+                     scale=D ** -0.5, interpret=interpret)[..., :D]
+
+
+def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
+              scale: float, interpret: bool):
     B, W, Hq, D = q.shape
     N, page_size, Hkv, _ = k_pages.shape
     P = tables.shape[1]
-    assert Hq % Hkv == 0, (Hq, Hkv)
-    assert (k_scale is None) == (v_scale is None), "pass both scales or none"
     quantized = k_scale is not None
     G = Hq // Hkv
-    scale = D ** -0.5
+    R = Hkv * W * G
+    bp = _block_pages(page_size, P)
+    bt = bp * page_size
 
     # (B, W, Hkv, G, D) -> (B, Hkv, W, G, D) -> rows stacked head-major:
-    # row r of the (W*G, D) tile is window position r // G, grouped head r % G.
+    # row r is KV head r // (W*G), window position (r % (W*G)) // G.
     qg = (q.reshape(B, W, Hkv, G, D).transpose(0, 2, 1, 3, 4)
-          .reshape(B, Hkv, W * G, D))
-    tables = tables.astype(jnp.int32)
+          .reshape(B, R, D))
+    # flat: a 2-D table in scalar memory pads its rows
+    tables = tables.astype(jnp.int32).reshape(-1)
     lengths = lengths.astype(jnp.int32)
-
-    def q_index(b, p, tbl, ln):
-        return (b, 0, 0, 0)
-
-    def kv_index(b, p, tbl, ln):
-        return (tbl[b, p], 0, 0, 0)
-
-    def scale_index(b, p, tbl, ln):
-        return (tbl[b, p], 0, 0)
-
-    kernel = functools.partial(_paged_mq_kernel, scale=scale,
-                               page_size=page_size, n_heads=Hkv, n_pages=P,
-                               window=W, group=G, quantized=quantized)
-    # Every block spans its array's last two dims in full, the layout the
-    # TPU compiler accepts for any Hkv, W*G and D.
-    in_specs = [
-        pl.BlockSpec((1, Hkv, W * G, D), q_index),
-        pl.BlockSpec((1, page_size, Hkv, D), kv_index),
-        pl.BlockSpec((1, page_size, Hkv, D), kv_index),
-    ]
-    operands = [qg, k_pages, v_pages]
+    # a page as (token, head) rows: the pool's own layout, a free reshape
+    rows = (N, page_size * Hkv)
+    operands = [qg, k_pages.reshape(rows + (D,)),
+                v_pages.reshape(rows + (D,))]
+    buffers = [pltpu.VMEM((2, bt * Hkv, D), k_pages.dtype),
+               pltpu.VMEM((2, bt * Hkv, D), v_pages.dtype)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, Hkv), scale_index),
-                     pl.BlockSpec((1, page_size, Hkv), scale_index)]
-        operands += [k_scale, v_scale]
+        # A page's scales as one lane row, padded to whole lane tiles: the
+        # TPU copies only tile-aligned slices out of HBM.  A small copy of
+        # the scale leaf (1/D of the values' elements), not of the pages.
+        lanes = -(-page_size * Hkv // 128) * 128
+        for sc in (k_scale, v_scale):
+            sc = sc.reshape(N, 1, page_size * Hkv)
+            operands.append(jnp.pad(
+                sc, ((0, 0), (0, 0), (0, lanes - page_size * Hkv))))
+            buffers.append(pltpu.VMEM((2, bp, 1, lanes), sc.dtype))
+
+    def slot_index(b, tbl, ln):
+        return (b, 0, 0)
+
+    kernel = functools.partial(
+        _paged_mq_kernel, scale=scale, page_size=page_size,
+        block_pages=bp, n_heads=Hkv, n_table=P, window=W, group=G,
+        quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hkv, W * G, D), q_index),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, W * G, D), jnp.float32),
-            pltpu.VMEM((Hkv, W * G, 1), jnp.float32),
-            pltpu.VMEM((Hkv, W * G, 1), jnp.float32),
+        grid=(B,),
+        # q and out blocks span their arrays' last two dims in full, the
+        # layout the TPU compiler accepts for any Hkv, W*G and D
+        in_specs=[pl.BlockSpec((1, R, D), slot_index)]
+        + [pl.BlockSpec(memory_space=pltpu.HBM)] * (len(operands) - 1),
+        out_specs=pl.BlockSpec((1, R, D), slot_index),
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((R, D), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, W * G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, R, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="paged_attention",
     )(tables, lengths, *operands)

@@ -210,10 +210,11 @@ def main():
                     help="re-place experts every N ticks from measured "
                     "per-expert token counts (load_balance-driven, "
                     "hot-expert replication; 0 = off)")
-    ap.add_argument("--pallas-attention", action="store_true",
+    ap.add_argument("--pallas-attention", action="store_true", default=None,
                     help="route paged decode/verify/prefill attention "
-                    "through the fused multi-query Pallas kernel "
-                    "(interpret-mode off-TPU; paged families only)")
+                    "through the fused multi-query Pallas kernel off a TPU "
+                    "too (interpret mode; paged families only); on a TPU "
+                    "the paged path takes it unasked")
     ap.add_argument("--kv-quant", choices=("int8", "off"), default="off",
                     help="store KV pages as int8 with per-(token, head) "
                     "scale leaves, dequantized inside attention — halves "

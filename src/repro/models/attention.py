@@ -118,11 +118,12 @@ def paged_window_attention(q, k_pages, v_pages, tables, n_cached, *,
     <= w; every window token's K/V must be written to its page before the
     call.  Returns (B, W, Hq, D).
 
-    ``use_pallas`` routes through the fused multi-query Pallas kernel
-    (:mod:`repro.kernels.paged_attention`), which gathers pages on-chip via
-    scalar-prefetched index maps and applies the per-row causal offset in
-    VMEM; the fallback materializes the gather with jnp advanced indexing
-    and reuses :func:`gqa_attention`'s masked path — identical math, the
+    ``use_pallas`` (what the serving engine passes on a TPU) routes
+    through the fused multi-query Pallas kernel
+    (:mod:`repro.kernels.paged_attention`), which copies each slot's live
+    pages into VMEM itself and applies the per-row causal offset there;
+    the fallback materializes the gather with jnp advanced indexing and
+    reuses :func:`gqa_attention`'s masked path — identical math, the
     kernel-parity oracle on the model side.
 
     Head counts are whatever the caller holds: under tensor-parallel serving

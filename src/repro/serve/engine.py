@@ -32,8 +32,8 @@ Layering (see README "Serving architecture"):
 * this module — pure execution: jitted device calls driven by the
   scheduler's plan.  ``paged_decode_step`` writes each slot's token K/V
   through (page, offset) targets and attends through the page table
-  (Pallas kernel :mod:`repro.kernels.paged_attention` or jnp gather
-  fallback); dead slots write to the pool's trash page so the SPMD tick
+  (Pallas kernel :mod:`repro.kernels.paged_attention` on a TPU, jnp
+  gather elsewhere); dead slots write to the pool's trash page so the SPMD tick
   keeps static shapes.
 
 Families whose decode state is per-token KV (dense / MoE / VLM stacked
@@ -183,7 +183,8 @@ class ServeEngine:
                  prefix_cache: bool = True,
                  spec_decode=None, spec_k: int = 4,
                  spec_temperature: float = 0.0,
-                 strict: bool = False, use_pallas_attention: bool = False,
+                 strict: bool = False,
+                 use_pallas_attention: Optional[bool] = None,
                  mesh=None, kv_quant=None, weight_quant=None,
                  prefill_only: bool = False, placement_interval: int = 0):
         self.model, self.params, self.rules = model, params, rules
@@ -229,7 +230,13 @@ class ServeEngine:
         # ``spec_decode`` — but it has no meaning for families whose decode
         # state is not paged KV (recurrent rwkv6/mamba2 scans, sliding-window
         # ring caches, or ``paged=False``), and silently ignoring it there
-        # would misreport what kernel actually ran.
+        # would misreport what kernel actually ran.  Unset, the paged path
+        # takes the kernel on a TPU, where it compiles and reads only each
+        # slot's live pages, and the jnp gather everywhere else (on the CPU
+        # the kernel would run in the Pallas interpreter).
+        if use_pallas_attention is None:
+            use_pallas_attention = (self.paged
+                                    and jax.default_backend() == "tpu")
         self.use_pallas_attention = bool(use_pallas_attention)
         if self.use_pallas_attention and not self.paged:
             raise ValueError(
@@ -438,7 +445,8 @@ class ServeEngine:
                           model.paged_leaf_specs(kvq)) if self.paged else 0,
                       "moe_tokens_routed": 0, "moe_dropped_tokens": 0,
                       "expert_tokens": [0] * n_exp,
-                      "expert_imbalance": 0.0, "placement_updates": 0}
+                      "expert_imbalance": 0.0, "placement_updates": 0,
+                      "decode_live_pages": 0, "decode_table_pages": 0}
 
         # donate the state/storage argument so XLA updates the KV buffers in
         # place (no full-pool copy per tick)
@@ -1107,10 +1115,10 @@ class ServeEngine:
             verify = bool(drafts) or (self.drafter is not None
                                       and self.spec_temperature > 0)
             with _span("serve.upload"):
-                args, cow_args = self._step_inputs(live, drafts, cow,
-                                                   verify)
+                args, cow_args, live_pages = self._step_inputs(
+                    live, drafts, cow, verify)
             try:
-                with _span("serve.step"):
+                with _span("serve.step", live_pages=live_pages):
                     if cow:     # copies strictly before this tick's writes
                         self.pool.storage = self._cow_copy(
                             self.pool.storage, *cow_args)
@@ -1162,7 +1170,14 @@ class ServeEngine:
         """The device arrays of one decode (or verify) step after the
         params and storage, and of its copy-on-write moves: every slot's
         page table, length, input tokens and write targets (dead slots
-        write to the trash page)."""
+        write to the trash page); and the pages its attention reads.
+
+        Those are Σ ceil(len / page_size) over live slots, ``len`` the
+        positions a slot's window attends to (cached tokens plus the
+        step's own).  The stats ``decode_live_pages`` and
+        ``decode_table_pages`` (max_slots × the table's width) sum them
+        over decode and verify steps: the share of the table that
+        attention over live pages reads."""
         ps = self.pool.page_size
         B = self.max_slots
         # verify width: the widest granted draft + 1, bucketed to two
@@ -1174,12 +1189,14 @@ class ServeEngine:
         woffs = np.zeros((B, C), np.int32)
         lens = np.zeros(B, np.int32)
         toks = np.zeros((B, C), np.int32)
+        live_pages = 0
         for slot in live:
             ln = int(self.sched.lengths[slot])
             lens[slot] = ln
             toks[slot, 0] = self.last_token[slot]
             d = drafts.get(slot)
             nd = 0 if d is None else len(d)
+            live_pages += -(-(ln + nd + 1) // ps)
             if nd:
                 toks[slot, 1:1 + nd] = d
             for i in range(nd + 1):
@@ -1202,7 +1219,9 @@ class ServeEngine:
         cow_args = (jnp.asarray([a for _, a, _ in cow], jnp.int32),
                     jnp.asarray([b for _, _, b in cow], jnp.int32)) \
             if cow else ()
-        return args, cow_args
+        self.stats["decode_live_pages"] += live_pages
+        self.stats["decode_table_pages"] += self.sched.table.size
+        return args, cow_args, live_pages
 
     # -- expert telemetry + load-aware placement -----------------------------
 

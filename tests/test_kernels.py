@@ -232,28 +232,96 @@ def test_paged_attention_bf16():
 # paged multi-query attention (decode / spec-verify / chunked-prefill windows)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("W", [1, 2, 8])
-@pytest.mark.parametrize("B,Hq,Hkv,D,ps,N,P", [
-    (3, 4, 2, 16, 8, 12, 4),      # GQA
-    (2, 4, 1, 32, 16, 6, 3),      # MQA
-    (1, 8, 8, 64, 8, 4, 2),       # MHA
-])
-def test_paged_attention_mq_vs_ref(W, B, Hq, Hkv, D, ps, N, P):
-    """Window kernel vs oracle across ragged per-row offsets: every slot at
-    a different cached length, including partial last pages and a window
-    whose last row lands exactly on the table's capacity."""
+def _block_tokens(ps, P):
+    from repro.kernels.paged_attention import _block_pages
+    return _block_pages(ps, P) * ps
+
+
+def _int8_pages(kp, vp):
+    from repro.serve.quant import Int8KVQuant
+    quant = Int8KVQuant()
+    qk, sk = quant.quantize(kp)
+    qv, sv = quant.quantize(vp)
+    return qk, qv, sk, sv
+
+
+# (W, Hq, Hkv, D, page_size, pages N, table width P).  Widths 41 and 20 are
+# no multiple of the 16- and 8-page blocks at page sizes 8 and 16.
+MQ_CASES = [(W, *shape) for W in (1, 2, 8) for shape in [
+    (4, 2, 16, 8, 12, 4),         # GQA, a table narrower than one block
+    (4, 1, 32, 16, 6, 3),         # MQA
+    (8, 8, 64, 8, 4, 2),          # MHA
+]] + [(W, *shape) for W in (1, 2, 64) for shape in [
+    (4, 2, 16, 8, 48, 41),        # GQA group 2, three blocks
+    (4, 4, 16, 8, 48, 41),        # group 1
+    (4, 2, 32, 16, 24, 20),       # page 16: two blocks of 8 pages
+]]
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("W,Hq,Hkv,D,ps,N,P", MQ_CASES)
+def test_paged_attention_mq_vs_ref(W, Hq, Hkv, D, ps, N, P, quantized):
+    """Window kernel vs oracle across ragged per-row offsets: one slot per
+    length of interest — nothing cached, one token, a page boundary, a
+    block boundary, and a window whose last row reads the table's last
+    page to its end — on float or int8 pages with their scales."""
+    cap = P * ps - W + 1                     # deepest row ends the table
+    lens = np.minimum([0, 1, ps, _block_tokens(ps, P), cap], cap)
+    B = len(lens)
     q = _arr((B, W, Hq, D))
     kp = _arr((N, ps, Hkv, D))
     vp = _arr((N, ps, Hkv, D))
+    scales = ()
+    if quantized:
+        kp, vp, *scales = _int8_pages(kp, vp)
     tables = jnp.asarray(RNG.integers(0, N, size=(B, P)), jnp.int32)
-    # row w of slot b sees lengths[b] + w keys; keep the deepest row in range
-    lens = RNG.integers(1, P * ps - W + 2, size=B)
-    lens[0] = P * ps - W + 1                 # full table for the last row
     lengths = jnp.asarray(lens, jnp.int32)
-    got = ops.paged_attention_mq(q, kp, vp, tables, lengths)
-    want = ref.paged_attention_mq(q, kp, vp, tables, lengths)
+    got = ops.paged_attention_mq(q, kp, vp, tables, lengths, *scales)
+    want = ref.paged_attention_mq(q, kp, vp, tables, lengths, *scales)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("W", [1, 4])
+def test_paged_attention_mq_reads_live_pages_only(W, quantized):
+    """NaN in every page a slot must not read — behind its dead table
+    entries and past its window's reach inside its last live page — leaves
+    the output finite and bit-identical to the unpoisoned run."""
+    Hq, Hkv, D, ps, P = 4, 2, 16, 8, 41      # 16-page blocks, width 41
+    lens = [1, 9, 130, 200]                  # reach 1..203+W-1 positions
+    B = len(lens)
+    live = [-(-(n + W - 1) // ps) for n in lens]
+    N = sum(live) + 1                        # + one page all dead entries
+    q = _arr((B, W, Hq, D))
+    kp = np.asarray(_arr((N, ps, Hkv, D)))
+    vp = np.asarray(_arr((N, ps, Hkv, D)))
+    tables = np.full((B, P), N - 1, np.int32)
+    pages = iter(RNG.permutation(N - 1))
+    poison = np.zeros((N, ps), bool)
+    poison[N - 1] = True
+    for b, (n, m) in enumerate(zip(lens, live)):
+        tables[b, :m] = [next(pages) for _ in range(m)]
+        tail = (n + W - 1) % ps              # the last page's unread rows
+        if tail:
+            poison[tables[b, m - 1], tail:] = True
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+
+    def run(poisoned: bool):
+        nan = poison[:, :, None, None] & poisoned
+        k = jnp.asarray(np.where(nan, np.nan, kp))
+        v = jnp.asarray(np.where(nan, np.nan, vp))
+        scales = ()
+        if quantized:
+            k, v, *scales = _int8_pages(k, v)
+            scales = [jnp.where(nan[..., 0], jnp.nan, s) for s in scales]
+        return np.asarray(ops.paged_attention_mq(q, k, v, tables, lengths,
+                                                 *scales))
+
+    clean = run(False)
+    got = run(True)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
 
 
 def test_paged_attention_mq_w1_is_bitwise_decode():

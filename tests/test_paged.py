@@ -266,6 +266,87 @@ def test_pallas_attention_flag_validated_at_construction():
         ServeEngine(rmodel, rparams, use_pallas_attention=True)
 
 
+def test_pallas_attention_default_is_the_gather_on_cpu():
+    """Unset, the paged engine attends through the jnp gather off a TPU."""
+    cfg = smoke_config("qwen2-7b").replace(remat="none")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    assert jax.default_backend() == "cpu"
+    eng = ServeEngine(model, params, max_slots=2, max_len=64, paged=True)
+    assert eng.use_pallas_attention is False
+    eng.close()
+
+
+def test_pallas_attention_default_is_the_kernel_on_tpu(monkeypatch):
+    """Unset on a TPU, the paged engine takes the kernel; a family with no
+    paged KV cache stays on its dense path instead of being refused."""
+    cfg = smoke_config("qwen2-7b").replace(remat="none")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    rcfg = smoke_config("rwkv6-3b").replace(remat="none")
+    rmodel = build_model(rcfg)
+    rparams = rmodel.init(jax.random.PRNGKey(0))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = ServeEngine(model, params, max_slots=2, max_len=64, paged=True)
+    assert eng.use_pallas_attention is True
+    eng.close()
+    dense = ServeEngine(model, params, max_slots=2, max_len=64, paged=False)
+    assert dense.use_pallas_attention is False
+    dense.close()
+    rec = ServeEngine(rmodel, rparams, max_slots=2, max_len=64)
+    assert rec.use_pallas_attention is False
+    rec.close()
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("flag", [False, True])
+def test_pallas_attention_explicit_flag_wins(monkeypatch, backend, flag):
+    cfg = smoke_config("qwen2-7b").replace(remat="none")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    eng = ServeEngine(model, params, max_slots=2, max_len=64, paged=True,
+                      use_pallas_attention=flag)
+    assert eng.use_pallas_attention is flag
+    eng.close()
+
+
+def test_decode_live_pages_counts_each_live_slots_pages():
+    """``decode_live_pages`` sums ceil(len / page_size) over live slots
+    (len: the positions the slot's window attends to), tick by tick; a
+    dead slot's stale length counts nothing, and ``decode_table_pages``
+    counts the whole table every step."""
+    cfg = smoke_config("qwen2-7b").replace(remat="none")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    ps = 8
+    eng = ServeEngine(model, params, max_slots=3, max_len=64, paged=True,
+                      page_size=ps, spec_decode="ngram", spec_k=3)
+    table = eng.sched.table.size
+    assert table == 3 * 64 // ps
+    # (live slots, cached lengths, drafts) of three hand-built steps; slot 1
+    # holds a stale length throughout and is never live
+    ticks = [([0, 2], {0: 7, 2: 16}, {}),
+             ([0, 2], {0: 8, 2: 17}, {}),
+             ([2], {2: 40}, {2: [4, 5, 6]})]
+    want = 0
+    for n, (live, lens, drafts) in enumerate(ticks, 1):
+        eng.sched.lengths[:] = 0
+        eng.sched.lengths[1] = 50
+        for slot, ln in lens.items():
+            eng.sched.lengths[slot] = ln
+        _, _, got = eng._step_inputs(live, drafts, [], bool(drafts))
+        step = sum(-(-(lens[s] + 1 + len(drafts.get(s, ()))) // ps)
+                   for s in live)
+        assert got == step
+        want += step
+        assert eng.stats["decode_live_pages"] == want
+        assert eng.stats["decode_table_pages"] == n * table
+    # 8 and 17 attended positions, then 9 and 18, then 40 + 4
+    assert want == (1 + 3) + (2 + 3) + 6
+    eng.close()
+
+
 def test_recurrent_family_keeps_dense_path():
     """rwkv6 has O(1) decode state — the engine auto-selects the dense slot
     path and still matches itself run-to-run; paged=True is refused."""

@@ -86,6 +86,9 @@ def test_paged_tick_spans_and_program_names(tmp_path, spec):
                       if n == "serve.prefill" and st["rid"] == r.rid)
         assert [s for s, _ in mine] == list(range(0, len(r.prompt), 16))
         assert sum(n for _, n in mine) == len(r.prompt)
+    # each step span carries the pages its attention reads
+    live = [st["live_pages"] for n, _, _, st in spans if n == "serve.step"]
+    assert sum(live) == eng.stats["decode_live_pages"] > 0
     step = "jit_verify_paged" if spec else "jit_decode_paged"
     assert {step, "jit_prefill_chunk", "jit_lm_head"} <= modules
     assert not any("lambda" in m for m in modules), modules
