@@ -37,6 +37,13 @@ class ModelConfig:
     capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
+    # the experts this model holds: ``n_held_experts`` of the router's
+    # ``n_experts``, from ``first_held_expert`` on (0: all of them).  A
+    # share stands for one chip of an expert-parallel deployment whose
+    # other chips hold the rest: routing covers all ``n_experts``, and only
+    # the held experts' part of each layer's output is computed.
+    first_held_expert: int = 0
+    n_held_experts: int = 0
 
     # SSM (mamba2) / hybrid
     ssm_state: int = 0
@@ -78,6 +85,15 @@ class ModelConfig:
     @property
     def padded_vocab(self) -> int:
         return _pad_to(self.vocab, max(self.tp * 8, 128))
+
+    @property
+    def held_experts(self) -> int:
+        """How many experts' weights this model holds."""
+        return self.n_held_experts or self.n_experts
+
+    @property
+    def holds_all_experts(self) -> bool:
+        return self.held_experts == self.n_experts
 
     @property
     def d_inner(self) -> int:

@@ -7,7 +7,9 @@ materializing the gathered (B, S, Hkv, D) cache in HBM — the jnp fallback in
 HBM->VMEM itself and keeps the online-softmax state (acc, m, l) in VMEM
 scratch.  Its cost follows the slots' live lengths, not the table width P.
 
-Grid = (B,), one step per slot.  Inside it a ``fori_loop`` runs over the
+Grid = (B,), one step per slot; (B, row blocks) where a slot's query rows
+exceed ``ROW_BLOCK``, each step then the rows of some whole KV heads (and
+the slot's pages read once a step).  Inside it a ``fori_loop`` runs over the
 slot's live blocks only: ``cdiv(lengths[b] + W - 1, block_tokens)`` of
 them, read from the scalar-prefetched ``lengths``, so a slot whose window
 reads nothing does no iterations.  A block is ``_block_pages`` whole pages
@@ -50,6 +52,7 @@ import jax.experimental.pallas.tpu as pltpu
 NEG_INF = -1e30
 BLOCK_TOKENS = 128          # a block's least width: one MXU tile of keys
 CHUNK_ROWS = 128            # query rows scored against a block at once
+ROW_BLOCK = 4096            # query rows one grid step holds (whole KV heads)
 # A wide window's call holds ~6 MB of VMEM as the v5e compiler counts it
 # for a described chip, and the chip's own count has read twice such a
 # figure: room above the 16 MB default scoped limit.
@@ -72,7 +75,7 @@ def _scale_row(buf, slot, block_pages: int, page_rows: int):
 def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
                      scale: float, page_size: int, block_pages: int,
                      n_heads: int, n_table: int, window: int, group: int,
-                     quantized: bool):
+                     quantized: bool, row_blocks: int):
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
          acc_ref, m_ref, l_ref) = rest
@@ -88,6 +91,9 @@ def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
     bt = block_pages * page_size
     page_rows = page_size * n_heads         # a page's rows: (token, head)
     rows = window * group                   # query rows of one KV head
+    # this step's query rows: whole KV heads, from head ``h0`` on
+    step_heads = n_heads // row_blocks
+    h0 = pl.program_id(1) * step_heads if row_blocks > 1 else 0
     n_blocks = jnp.minimum((reach + bt - 1) // bt,
                            -(-n_table // block_pages))
 
@@ -158,20 +164,22 @@ def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
             vs = jnp.where(col_tok < reach, vs, 0.0)
 
         # Query rows are (head, window position, group) — row r serves
-        # KV head r // rows and window position (r % rows) // group — and
-        # are scored in chunks of whole heads against every block row; a
-        # score counts only where the row's head is the column's.  A chunk
-        # is the most heads that divide Hkv and fit CHUNK_ROWS (one head
-        # at least).
-        per_chunk = max(d for d in range(1, n_heads + 1)
-                        if n_heads % d == 0
+        # KV head h0 + r // rows and window position (r % rows) // group —
+        # and are scored in chunks against every block row; a score counts
+        # only where the row's head is the column's.  A chunk is the most
+        # whole heads that divide the step's heads and fit CHUNK_ROWS, or
+        # CHUNK_ROWS rows of one head whose rows exceed it.
+        per_chunk = max(d for d in range(1, step_heads + 1)
+                        if step_heads % d == 0
                         and (d == 1 or d * rows <= CHUNK_ROWS))
         n = per_chunk * rows
+        if rows > CHUNK_ROWS and rows % CHUNK_ROWS == 0:
+            n = CHUNK_ROWS
 
         def attend(r0):
             row = r0 + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
             valid = jnp.logical_and(
-                col_head == row // rows,
+                col_head == h0 + row // rows,
                 col_tok < length + (row % rows) // group)    # (n, bt*Hkv)
             s = jax.lax.dot_general(q_ref[0, pl.ds(r0, n)], k,
                                     (((1,), (1,)), ((), ())),
@@ -199,7 +207,7 @@ def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
                                       preferred_element_type=jnp.float32))
             m_ref[pl.ds(r0, n)] = m_new
 
-        n_chunks = n_heads // per_chunk
+        n_chunks = step_heads * rows // n
         if n_chunks > 1 and n % 16 == 0:
             # a loop, not unrolled code: one chunk's temporaries in VMEM
             def chunk(j, carry):
@@ -261,6 +269,13 @@ def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
     R = Hkv * W * G
     bp = _block_pages(page_size, P)
     bt = bp * page_size
+    # the grid splits a slot's query rows into steps of whole KV heads, as
+    # many as fit ROW_BLOCK rows (one at least): wide windows of many heads
+    # (a prefill chunk at a GQA group of 16) would not fit VMEM at once
+    step_heads = max(d for d in range(1, Hkv + 1) if Hkv % d == 0
+                     and (d == 1 or d * W * G <= ROW_BLOCK))
+    row_blocks = Hkv // step_heads
+    RB = R // row_blocks
 
     # (B, W, Hkv, G, D) -> (B, Hkv, W, G, D) -> rows stacked head-major:
     # row r is KV head r // (W*G), window position (r % (W*G)) // G.
@@ -286,26 +301,35 @@ def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
                 sc, ((0, 0), (0, 0), (0, lanes - page_size * Hkv))))
             buffers.append(pltpu.VMEM((2, bp, 1, lanes), sc.dtype))
 
-    def slot_index(b, tbl, ln):
-        return (b, 0, 0)
+    if row_blocks == 1:
+        grid = (B,)
+
+        def rows_index(b, tbl, ln):
+            return (b, 0, 0)
+    else:
+        grid = (B, row_blocks)
+
+        def rows_index(b, j, tbl, ln):
+            return (b, j, 0)
 
     kernel = functools.partial(
         _paged_mq_kernel, scale=scale, page_size=page_size,
         block_pages=bp, n_heads=Hkv, n_table=P, window=W, group=G,
-        quantized=quantized)
+        quantized=quantized, row_blocks=row_blocks)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B,),
-        # q and out blocks span their arrays' last two dims in full, the
-        # layout the TPU compiler accepts for any Hkv, W*G and D
-        in_specs=[pl.BlockSpec((1, R, D), slot_index)]
+        grid=grid,
+        # q and out blocks span their arrays' last dim in full and whole
+        # KV heads of rows, the layout the TPU compiler accepts for any
+        # Hkv, W*G and D
+        in_specs=[pl.BlockSpec((1, RB, D), rows_index)]
         + [pl.BlockSpec(memory_space=pltpu.HBM)] * (len(operands) - 1),
-        out_specs=pl.BlockSpec((1, R, D), slot_index),
+        out_specs=pl.BlockSpec((1, RB, D), rows_index),
         scratch_shapes=buffers + [
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((R, D), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((RB, D), jnp.float32),
+            pltpu.VMEM((RB, 1), jnp.float32),
+            pltpu.VMEM((RB, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -313,7 +337,7 @@ def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, R, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            dimension_semantics=("parallel",) * len(grid),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="paged_attention",

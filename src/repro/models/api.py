@@ -274,11 +274,13 @@ class Model:
                     dims["d_ff"] = cfg.d_ff
                 bad += [f"{k}={v} (tp={tp})" for k, v in dims.items()
                         if v % tp]
-            if cfg.n_experts and cfg.n_experts % (ep * tp):
+            if cfg.n_experts and cfg.held_experts % (ep * tp):
                 # experts shard over BOTH axes (tp slices expert rows even
                 # on a 1-D mesh), so the product must divide the stack
                 shards = (f"ep*tp={ep * tp}" if ep > 1 else f"tp={tp}")
-                bad.append(f"n_experts={cfg.n_experts} ({shards})")
+                what = ("n_experts" if cfg.holds_all_experts
+                        else "held_experts")
+                bad.append(f"{what}={cfg.held_experts} ({shards})")
         elif ep > 1:
             bad.append(f"family={cfg.family} has no paged expert path "
                        f"(ep={ep})")

@@ -38,12 +38,19 @@ PLACE_Q = 256
 
 
 def moe_def(cfg) -> dict:
+    """The router keeps all ``n_experts`` outputs; the expert stacks hold
+    the config's share (``cfg.held_experts`` of them)."""
     d, E, eff = cfg.d_model, cfg.n_experts, cfg.expert_d_ff
+    H, lo = cfg.held_experts, cfg.first_held_expert
+    if not (0 < H and 0 <= lo and lo + H <= E):
+        raise ValueError(
+            f"{cfg.name}: held experts {lo}..{lo + H - 1} are not a range of "
+            f"the router's {E} experts")
     return {
         "router": Param((d, E), P("embed", None), init="small"),
-        "gate": Param((E, d, eff), P("experts", "expert_embed", "expert_mlp")),
-        "up": Param((E, d, eff), P("experts", "expert_embed", "expert_mlp")),
-        "down": Param((E, eff, d), P("experts", "expert_mlp", "expert_embed")),
+        "gate": Param((H, d, eff), P("experts", "expert_embed", "expert_mlp")),
+        "up": Param((H, d, eff), P("experts", "expert_embed", "expert_mlp")),
+        "down": Param((H, eff, d), P("experts", "expert_mlp", "expert_embed")),
     }
 
 
@@ -98,16 +105,30 @@ def _dispatch_compute_combine(x2d, wr, wg, wu, wd, cfg, comm, tp_comm=None,
     identity map produce the exact integer slot indices of the unplaced
     path, so streams are bitwise unchanged.
 
+    A config that holds a share of the experts (``cfg.held_experts`` from
+    ``cfg.first_held_expert`` on; the weight stacks hold only those) routes
+    over all ``n_experts`` and computes only the held experts' part of
+    ``y``.  Assignments to experts it does not hold are the work of the
+    chips that hold them: they are neither computed nor counted as
+    dropped.  Such a share is dropless (``C = T_l``): it sees only its own
+    chip's tokens, so a budget balanced over all experts has no meaning
+    there, and since top-k picks distinct experts no expert can receive
+    more than ``T_l`` assignments.  The dispatch buffer spans the held
+    experts only, ``(held, C, d)``.
+
     Returns ``(y, aux, stats)`` with int32 per-logical-expert telemetry
     ``stats = {"tokens": routed assignments (top_k multiplicity), "dropped":
-    assignments lost to capacity or eviction}`` — local to this shard's
-    tokens (replicated = global in serving)."""
+    assignments to held experts lost to capacity or eviction}`` — local to
+    this shard's tokens (replicated = global in serving)."""
     T_l, d = x2d.shape
     E, k = cfg.n_experts, cfg.top_k
+    H, lo = cfg.held_experts, cfg.first_held_expert
     ep = comm.size()
-    assert E % ep == 0, (E, ep)
-    E_loc = E // ep
+    assert H % ep == 0, (H, ep)
+    E_loc = H // ep
     C = capacity(T_l, k, E, cfg.capacity_factor)
+    if not cfg.holds_all_experts:
+        C = T_l
 
     # --- route ------------------------------------------------------------
     logits = (x2d.astype(jnp.float32) @ wr.astype(jnp.float32))      # (T_l, E)
@@ -142,15 +163,17 @@ def _dispatch_compute_combine(x2d, wr, wg, wu, wd, cfg, comm, tp_comm=None,
         slot_e = jnp.where(use_b, slot_b[sorted_e], slot_a[sorted_e])
         pos = jnp.where(use_b, pos_in_e - sp, pos_in_e)
         keep = keep & (slot_e >= 0)                      # evicted -> dropped
-    slot = jnp.where(keep, slot_e * C + pos, E * C)      # drop -> OOB
-    buf = jnp.zeros((E * C + 1, d), x2d.dtype).at[slot].set(
+    keep = keep & (sorted_e >= lo) & (sorted_e < lo + H)  # held here
+    slot = jnp.where(keep, (slot_e - lo) * C + pos, H * C)   # drop -> OOB
+    buf = jnp.zeros((H * C + 1, d), x2d.dtype).at[slot].set(
         x2d[sorted_tok], mode="drop")
-    buf = buf[:-1].reshape(E, C, d)
+    buf = buf[:-1].reshape(H, C, d)
 
     # --- telemetry: per-logical-expert routed / dropped assignments ---------
     routed = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
     kept = jnp.zeros((E,), jnp.int32).at[sorted_e].add(keep.astype(jnp.int32))
-    stats = {"tokens": routed, "dropped": routed - kept}
+    held = (jnp.arange(E) >= lo) & (jnp.arange(E) < lo + H)
+    stats = {"tokens": routed, "dropped": jnp.where(held, routed - kept, 0)}
 
     # --- EP exchange: redistribute_work on the torus ------------------------
     buf = comm.all_to_all(buf, split_axis=0, concat_axis=1)          # (E_loc, C*ep, d)
@@ -174,9 +197,9 @@ def _dispatch_compute_combine(x2d, wr, wg, wu, wd, cfg, comm, tp_comm=None,
         out = shard_comm.all_gather(out, axis=0, tiled=True)         # (E_loc, C, d)
 
     # --- return + combine ----------------------------------------------------
-    out = comm.all_to_all(out, split_axis=1, concat_axis=0)          # (E, C, d)
-    out = out.reshape(E * C, d)
-    gathered = jnp.where(keep[:, None], out[jnp.clip(slot, 0, E * C - 1)], 0.0)
+    out = comm.all_to_all(out, split_axis=1, concat_axis=0)          # (H, C, d)
+    out = out.reshape(H * C, d)
+    gathered = jnp.where(keep[:, None], out[jnp.clip(slot, 0, H * C - 1)], 0.0)
     w_sorted = top_p.reshape(-1)[order]
     contrib = gathered * w_sorted[:, None].astype(gathered.dtype)
     y = jnp.zeros((T_l, d), x2d.dtype).at[sorted_tok].add(contrib)

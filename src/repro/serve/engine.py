@@ -385,6 +385,12 @@ class ServeEngine:
         from repro.serve import placement as PL
         n_exp = model.cfg.n_experts if (self.paged and model.cfg.n_experts) \
             else 0
+        if placement_interval and n_exp and not model.cfg.holds_all_experts:
+            raise ValueError(
+                f"{model.cfg.name} holds {model.cfg.held_experts} of its "
+                f"{n_exp} experts: load-aware placement moves experts "
+                "between slots that all hold weights; drop "
+                "placement_interval")
         self.placement = None                   # PlacementPlan once updated
         self.placement_interval = int(placement_interval)
         self._params_unplaced = self.params     # pristine expert order
@@ -444,6 +450,8 @@ class ServeEngine:
                       "kv_bytes_per_token": QZ.kv_bytes_per_token(
                           model.paged_leaf_specs(kvq)) if self.paged else 0,
                       "moe_tokens_routed": 0, "moe_dropped_tokens": 0,
+                      "moe_local_assignments": 0,
+                      "moe_remote_assignments": 0,
                       "expert_tokens": [0] * n_exp,
                       "expert_imbalance": 0.0, "placement_updates": 0,
                       "decode_live_pages": 0, "decode_table_pages": 0}
@@ -1227,15 +1235,25 @@ class ServeEngine:
 
     def _account_moe(self, tel) -> None:
         """Fold one step's per-expert telemetry into engine stats (counts
-        are replicated across the mesh, so any shard's copy is global)."""
+        are replicated across the mesh, so any shard's copy is global).
+        Assignments to the experts this model holds are local, the rest
+        remote: the work of the chips that hold those experts."""
         if self._expert_tokens.size == 0:
             return
-        t = np.asarray(jax.device_get(tel["expert_tokens"]), np.int64)
-        d = np.asarray(jax.device_get(tel["expert_dropped"]), np.int64)
+        with _span("serve.moe_stats"):
+            t, d = jax.device_get((tel["expert_tokens"],
+                                   tel["expert_dropped"]))
+        t = np.asarray(t, np.int64)
+        d = np.asarray(d, np.int64)
+        cfg = self.model.cfg
+        local = int(t[cfg.first_held_expert:
+                      cfg.first_held_expert + cfg.held_experts].sum())
         self._expert_tokens += t
         self._expert_window += t
         self.stats["moe_tokens_routed"] += int(t.sum())
         self.stats["moe_dropped_tokens"] += int(d.sum())
+        self.stats["moe_local_assignments"] += local
+        self.stats["moe_remote_assignments"] += int(t.sum()) - local
         self.stats["expert_tokens"] = self._expert_tokens.tolist()
         if self._expert_window.sum():
             from repro.serve import placement as PL
@@ -1258,6 +1276,10 @@ class ServeEngine:
             raise ValueError(
                 f"{self.model.cfg.name}: expert placement needs a paged "
                 "MoE model")
+        if not self.model.cfg.holds_all_experts:
+            raise ValueError(
+                f"{self.model.cfg.name}: expert placement needs every "
+                "expert's weights; this model holds a share")
         if plan is None:
             if not self._expert_window.sum():
                 return None
