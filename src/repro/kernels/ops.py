@@ -46,12 +46,13 @@ def paged_attention(q, k_pages, v_pages, tables, lengths):
 
 @jax.jit
 def paged_attention_mq(q, k_pages, v_pages, tables, lengths,
-                       k_scale=None, v_scale=None):
+                       k_scale=None, v_scale=None, layer=None):
     # k_scale/v_scale: optional (N, page_size, Hkv) int8-page dequant
-    # scales, copied beside their pages and applied in VMEM
+    # scales, copied beside their pages and applied in VMEM; with
+    # ``layer`` the pages are a stacked (L, N, ...) pool read at that layer
     return _pa.paged_attention_mq(q, k_pages, v_pages, tables, lengths,
                                   k_scale=k_scale, v_scale=v_scale,
-                                  interpret=_interpret())
+                                  layer=layer, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "rows_blk"))

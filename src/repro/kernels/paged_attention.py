@@ -72,18 +72,21 @@ def _scale_row(buf, slot, block_pages: int, page_rows: int):
                             for j in range(block_pages)], axis=-1)
 
 
-def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
+def _paged_mq_kernel(tbl_ref, len_ref, base_ref, q_ref, k_hbm, v_hbm, *rest,
                      scale: float, page_size: int, block_pages: int,
                      n_heads: int, n_table: int, window: int, group: int,
                      quantized: bool, row_blocks: int):
+    # value pages lie ``base`` pages into their (stacked) pool, scale pages
+    # at the table's own ids: see :func:`_paged_mq`
+    base = base_ref[0]
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
          acc_ref, m_ref, l_ref) = rest
-        leaves = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
-                  (vs_hbm, vs_buf))
+        leaves = ((k_hbm, k_buf, base), (v_hbm, v_buf, base),
+                  (ks_hbm, ks_buf, 0), (vs_hbm, vs_buf, 0))
     else:
         o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref = rest
-        leaves = ((k_hbm, k_buf), (v_hbm, v_buf))
+        leaves = ((k_hbm, k_buf, base), (v_hbm, v_buf, base))
     b = pl.program_id(0)
     length = len_ref[b]
     # the deepest-reaching row (w = window-1) reads positions < reach
@@ -110,11 +113,11 @@ def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
             @pl.when(jnp.logical_and(idx < n_table,
                                      idx * page_size < reach))
             def _():
-                for src, buf in leaves:
+                for src, buf, first in leaves:
                     # values land as rows of the block, scales as a page
                     dst = (buf.at[slot, pl.ds(row, page_rows)]
                            if buf.ndim == 3 else buf.at[slot, j])
-                    cp = pltpu.make_async_copy(src.at[page], dst,
+                    cp = pltpu.make_async_copy(src.at[first + page], dst,
                                                sems.at[slot])
                     if start:
                         cp.start()
@@ -225,7 +228,7 @@ def _paged_mq_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
 
 
 def paged_attention_mq(q, k_pages, v_pages, tables, lengths, *,
-                       k_scale=None, v_scale=None,
+                       k_scale=None, v_scale=None, layer=None,
                        interpret: bool = False):
     """q: (B, W, Hq, D); k_pages/v_pages: (N, page_size, Hkv, D);
     tables: (B, P) int32 page ids; lengths: (B,) int32 valid-KV counts for
@@ -243,26 +246,37 @@ def paged_attention_mq(q, k_pages, v_pages, tables, lengths, *,
     beside its values and applied in VMEM, to the scores (K) and to the
     probabilities (V) — the page stream out of HBM stays int8, which is
     where the 4x bandwidth cut happens.
+
+    ``layer``: with it, ``k_pages``/``v_pages`` are a stacked pool of
+    layers, (L, N, page_size, Hkv, D), whose layer ``layer`` (a traced
+    int32) the kernel reads in place; ``tables`` and the scale leaves are
+    that layer's own.  What a call copies whole (its scale leaves as lane
+    rows, a head dim padded to lanes) is thus one layer's at most.
     """
     D = q.shape[-1]
-    assert q.shape[2] % k_pages.shape[2] == 0, (q.shape, k_pages.shape)
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    assert q.shape[2] % k_pages.shape[3] == 0, (q.shape, k_pages.shape)
     assert (k_scale is None) == (v_scale is None), "pass both scales or none"
     if D % 128 == 0:
         return _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale,
-                         v_scale, scale=D ** -0.5, interpret=interpret)
+                         v_scale, layer, scale=D ** -0.5,
+                         interpret=interpret)
     # the TPU copies only whole 128-lane rows out of HBM: zero-pad the head
-    # dim (a copy of the pool, for heads narrower than a lane tile only;
-    # zeros change no dot product)
-    pad = ((0, 0),) * 3 + ((0, -D % 128),)
-    q, k_pages, v_pages = (jnp.pad(x, pad) for x in (q, k_pages, v_pages))
+    # dim (a copy of the layer's pages, for heads narrower than a lane tile
+    # only; zeros change no dot product)
+    pad = ((0, 0),) * 4 + ((0, -D % 128),)
+    q = jnp.pad(q, pad[1:])
+    k_pages, v_pages = (jnp.pad(x[layer][None], pad)
+                        for x in (k_pages, v_pages))
     return _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale,
-                     scale=D ** -0.5, interpret=interpret)[..., :D]
+                     0, scale=D ** -0.5, interpret=interpret)[..., :D]
 
 
-def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
-              scale: float, interpret: bool):
+def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale,
+              layer, *, scale: float, interpret: bool):
     B, W, Hq, D = q.shape
-    N, page_size, Hkv, _ = k_pages.shape
+    n_layers, N, page_size, Hkv, _ = k_pages.shape
     P = tables.shape[1]
     quantized = k_scale is not None
     G = Hq // Hkv
@@ -284,8 +298,11 @@ def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
     # flat: a 2-D table in scalar memory pads its rows
     tables = tables.astype(jnp.int32).reshape(-1)
     lengths = lengths.astype(jnp.int32)
-    # a page as (token, head) rows: the pool's own layout, a free reshape
-    rows = (N, page_size * Hkv)
+    # a page as (token, head) rows: the pool's own layout, a free reshape;
+    # the layers' pools one after another, layer ``layer``'s from page
+    # ``base`` on
+    rows = (n_layers * N, page_size * Hkv)
+    base = (jnp.asarray(layer, jnp.int32) * N).reshape(1)
     operands = [qg, k_pages.reshape(rows + (D,)),
                 v_pages.reshape(rows + (D,))]
     buffers = [pltpu.VMEM((2, bt * Hkv, D), k_pages.dtype),
@@ -293,7 +310,8 @@ def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
     if quantized:
         # A page's scales as one lane row, padded to whole lane tiles: the
         # TPU copies only tile-aligned slices out of HBM.  A small copy of
-        # the scale leaf (1/D of the values' elements), not of the pages.
+        # the layer's scale leaf (1/D of its values' elements), not of the
+        # pages.
         lanes = -(-page_size * Hkv // 128) * 128
         for sc in (k_scale, v_scale):
             sc = sc.reshape(N, 1, page_size * Hkv)
@@ -304,12 +322,12 @@ def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
     if row_blocks == 1:
         grid = (B,)
 
-        def rows_index(b, tbl, ln):
+        def rows_index(b, tbl, ln, base):
             return (b, 0, 0)
     else:
         grid = (B, row_blocks)
 
-        def rows_index(b, j, tbl, ln):
+        def rows_index(b, j, tbl, ln, base):
             return (b, j, 0)
 
     kernel = functools.partial(
@@ -317,7 +335,7 @@ def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
         block_pages=bp, n_heads=Hkv, n_table=P, window=W, group=G,
         quantized=quantized, row_blocks=row_blocks)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         # q and out blocks span their arrays' last dim in full and whole
         # KV heads of rows, the layout the TPU compiler accepts for any
@@ -341,7 +359,7 @@ def _paged_mq(q, k_pages, v_pages, tables, lengths, k_scale, v_scale, *,
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="paged_attention",
-    )(tables, lengths, *operands)
+    )(tables, lengths, base, *operands)
     return (out.reshape(B, Hkv, W, G, D).transpose(0, 2, 1, 3, 4)
             .reshape(B, W, Hq, D))
 

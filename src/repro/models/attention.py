@@ -105,7 +105,7 @@ def _mask_bias(q_pos, k_pos, *, causal: bool, window: Optional[int],
 
 
 def paged_window_attention(q, k_pages, v_pages, tables, n_cached, *,
-                           k_scale=None, v_scale=None,
+                           k_scale=None, v_scale=None, layer=None,
                            use_pallas: bool = False):
     """Attention for a window of queries against paged KV storage — the ONE
     model-side paged-attention path (decode W=1, speculative verify, and
@@ -137,8 +137,13 @@ def paged_window_attention(q, k_pages, v_pages, tables, n_cached, *,
     multiply into the VMEM page tile (the page stream stays int8 in HBM);
     this fallback dequantizes right after the gather — same math, the
     quantized kernel's parity oracle.
+
+    ``layer``: with it, ``k_pages``/``v_pages`` are a stacked pool of
+    layers, (L, N, page_size, Hkv, D), and attention reads layer
+    ``layer``'s pages (a traced int32) in place: none are sliced out of the
+    pool.  ``tables`` and the scale leaves are that layer's own.
     """
-    Hq, Hkv = q.shape[2], k_pages.shape[2]
+    Hq, Hkv = q.shape[2], k_pages.shape[-2]
     if Hkv == 0 or Hq % Hkv:
         raise ValueError(
             f"Hq={Hq} must be a positive multiple of Hkv={Hkv}; under "
@@ -150,11 +155,18 @@ def paged_window_attention(q, k_pages, v_pages, tables, n_cached, *,
         lengths = jnp.broadcast_to(
             jnp.asarray(n_cached, jnp.int32) + 1, (q.shape[0],))
         return kops.paged_attention_mq(q, k_pages, v_pages, tables, lengths,
-                                       k_scale, v_scale)
+                                       k_scale, v_scale, layer)
     from repro.optim.compress import int8_decompress
     from repro.serve import pages as PG
-    k = PG.gather_pages(k_pages, tables)            # (B, P*page_size, Hkv, D)
-    v = PG.gather_pages(v_pages, tables)
+    at = tables
+    if layer is not None:
+        # the stacked pool viewed as (L·N, ...): the layer's pages lie
+        # layer·N pages in
+        at = tables + layer * k_pages.shape[1]
+        k_pages, v_pages = (a.reshape((-1,) + a.shape[2:])
+                            for a in (k_pages, v_pages))
+    k = PG.gather_pages(k_pages, at)                # (B, P*page_size, Hkv, D)
+    v = PG.gather_pages(v_pages, at)
     if k_scale is not None:
         k = int8_decompress(k, PG.gather_pages(k_scale, tables),
                             axis=-1, dtype=q.dtype)

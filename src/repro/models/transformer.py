@@ -334,7 +334,7 @@ def prefill(params, cfg, rules, tokens=None, inputs_embeds=None,
 # ---------------------------------------------------------------------------
 
 def _write_kv(kv, k, v, quant, scatter):
-    """Commit fresh K/V into one layer's page storage through a pure
+    """Commit fresh K/V into page storage through a pure
     ``scatter(leaf_storage, vals) -> leaf_storage`` op.
 
     With a ``quant`` policy the values are quantized first and the
@@ -354,17 +354,19 @@ def _write_kv(kv, k, v, quant, scatter):
 
 
 def _paged_block(p, x, cfg, rules, *, positions, kv, tables,
-                 q_offset, write, use_pallas=False, comm=_SERIAL,
+                 q_offset, write, layer=None, use_pallas=False, comm=_SERIAL,
                  ep_comm=None, placement=None):
-    """One decoder block against paged KV storage (per-layer page slices).
+    """One decoder block against paged KV storage.
 
-    ``kv`` is this layer's slice of the pool storage tree — ``{"k", "v"}``
+    ``kv`` is the pool storage tree the block writes into — ``{"k", "v"}``
     pages, plus ``{"k_scale", "v_scale"}`` per-row scale leaves when the
     cache is quantized.  ``write(kv, k, v) -> kv`` commits the fresh K/V
     into pages — a whole-chunk scatter during prefill, a per-slot token
     scatter during decode, a per-slot window scatter during verify — so
-    this block stays agnostic of which phase it runs in.  Attention is one
-    call for all three phases:
+    this block stays agnostic of which phase it runs in.  With ``layer``,
+    ``kv`` is the pool of every layer, (L·N, page_size, ...), and attention
+    reads that layer's pages (:func:`_layer_pages`).  Attention is one call
+    for all three phases:
     :func:`repro.models.attention.paged_window_attention` with ``q_offset``
     tokens cached before the query window, fused Pallas kernel or jnp
     gather fallback per ``use_pallas`` (both dequantize scale leaves when
@@ -389,10 +391,12 @@ def _paged_block(p, x, cfg, rules, *, positions, kv, tables,
         h = L.rmsnorm(p["ln1"], x, use_pallas=cfg.use_pallas)
         q, k, v = A.qkv_project(p["attn"], h, cfg, positions, rules=rules)
         kv = write(kv, k, v)
-        o = A.paged_window_attention(q, kv["k"], kv["v"], tables, q_offset,
-                                     k_scale=kv.get("k_scale"),
-                                     v_scale=kv.get("v_scale"),
-                                     use_pallas=use_pallas)
+        pages = kv if layer is None else _layer_pages(kv, layer,
+                                                      cfg.n_layers)
+        o = A.paged_window_attention(q, pages["k"], pages["v"], tables,
+                                     q_offset, k_scale=pages.get("k_scale"),
+                                     v_scale=pages.get("v_scale"),
+                                     layer=layer, use_pallas=use_pallas)
         x = x + comm.all_reduce_sum(A.out_project(p["attn"], o))
 
     with jax.named_scope("mlp"):
@@ -417,6 +421,66 @@ def _paged_block(p, x, cfg, rules, *, positions, kv, tables,
     return x + y, kv, moe_stats
 
 
+def _layer_pages(pool, layer, n_layers):
+    """Layer ``layer``'s pages of a pool of every layer, (L·N, page_size,
+    ...), as attention reads them: the value leaves as the stacked pool (L,
+    N, ...), a view that moves no bytes, read in place; the scale leaves
+    (1/D of the values') as the layer's own (N, ...) slice."""
+    n_pages = pool["k"].shape[0] // n_layers
+    return {name: (jax.lax.dynamic_slice_in_dim(a, layer * n_pages, n_pages)
+                   if name.endswith("_scale")
+                   else a.reshape((n_layers, n_pages) + a.shape[1:]))
+            for name, a in pool.items()}
+
+
+def _paged_layers(params, cfg, rules, x, storage, scatter, *, positions,
+                  tables, q_offset, use_pallas, comm, quant, ep_comm,
+                  placement):
+    """Every decoder block of a paged program, over the stacked pool.
+
+    ``storage`` leaves are (L, N, page_size, ...), and the whole pool is
+    the layer scan's carry.  Layer ``l`` writes through
+    ``scatter(leaf, vals, off)`` on the pool viewed as (L·N, page_size,
+    ...) — a merge of the two leading dims, which moves no bytes — with
+    every page id it writes offset by ``off`` = ``l·N``, so its trash page
+    is its own; attention reads layer ``l``'s pages of the stacked pool
+    through the layer's own ``tables``.  So each layer scatters only the
+    program's new rows into the pool, in place, and no layer's pages are
+    sliced out, written back or copied.  The int8 scale leaves share the
+    (L, N) dims and ride along; attention gets the layer's slice of them
+    (:func:`_layer_pages`), a 1/D share of its pages.
+
+    Returns (storage, hidden, telemetry) with telemetry the layer-summed
+    per-expert ``{"expert_tokens", "expert_dropped"}`` int32 counts
+    ((0,)-shaped for dense models).
+    """
+    n_pages = storage["k"].shape[1]
+    flat = jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), storage)
+
+    def body(carry, p):
+        x, tok, drp, kv, layer = carry
+
+        def put(st, val):
+            return scatter(st, val, layer * n_pages)
+
+        x, kv, ms = _paged_block(
+            p, x, cfg, rules, positions=positions, kv=kv, tables=tables,
+            q_offset=q_offset,
+            write=lambda kv, k, v: _write_kv(kv, k, v, quant, put),
+            layer=layer, use_pallas=use_pallas, comm=comm, ep_comm=ep_comm,
+            placement=placement)
+        return (x, tok + ms["tokens"], drp + ms["dropped"], kv,
+                layer + 1), None
+
+    z = jnp.zeros((cfg.n_experts,), jnp.int32)
+    (x, tok, drp, flat, _), _ = jax.lax.scan(
+        body, (x, z, z, flat, jnp.int32(0)), params["blocks"])
+    storage = jax.tree_util.tree_map(lambda a, s: a.reshape(s.shape),
+                                     flat, storage)
+    return storage, x, {"expert_tokens": tok, "expert_dropped": drp}
+
+
 def paged_prefill_chunk(params, cfg, rules, storage, table_row, pages_chunk,
                         start, tokens, use_pallas=False, comm=None,
                         quant=None, ep_comm=None, placement=None,
@@ -437,6 +501,10 @@ def paged_prefill_chunk(params, cfg, rules, storage, table_row, pages_chunk,
     multi-query kernel (W = C window, per-row causal offsets) instead of
     the jnp gather fallback.
 
+    The stacked pool is the layer scan's carry (:func:`_paged_layers`):
+    layer ``l`` scatters the chunk's rows into its pages ``pages_chunk`` in
+    place and attends to its pages of the stacked pool.
+
     ``quant`` is the KV quantization policy (quantize-on-write; attention
     dequantizes through the scale leaves) — prefilled pages hold the SAME
     int8 content a decode write would produce, which is what keeps
@@ -454,7 +522,6 @@ def paged_prefill_chunk(params, cfg, rules, storage, table_row, pages_chunk,
     """
     from repro.serve import pages as PG
     assert not uses_window_cache(cfg), "paged decode is global-attention only"
-    comm = _SERIAL if comm is None else comm
     page_size = storage["k"].shape[2]
     if embeds is None:
         x = embed_tokens(params, tokens, cfg, rules)
@@ -462,30 +529,19 @@ def paged_prefill_chunk(params, cfg, rules, storage, table_row, pages_chunk,
         x = embed_tokens(params, jnp.maximum(tokens, 0), cfg, rules)
         x = jnp.where((tokens < 0)[..., None], embeds.astype(x.dtype), x)
     C = x.shape[1]
-    positions = start + jnp.arange(C)
-    tables = table_row[None]                                    # (1, P)
 
-    def write(kv, k, v):
-        return _write_kv(
-            kv, k[0], v[0], quant,
-            lambda st, val: PG.scatter_chunk(st, pages_chunk, val,
-                                             page_size=page_size))
+    def scatter(st, val, off):
+        return PG.scatter_chunk(st, pages_chunk + off, val[0],
+                                page_size=page_size)
 
-    def body(carry, xs):
-        x, tok, drp = carry
-        p, kv = xs
-        x, kv, ms = _paged_block(p, x, cfg, rules, positions=positions,
-                                 kv=kv, tables=tables,
-                                 q_offset=start, write=write,
-                                 use_pallas=use_pallas, comm=comm,
-                                 ep_comm=ep_comm, placement=placement)
-        return (x, tok + ms["tokens"], drp + ms["dropped"]), kv
-
-    z = jnp.zeros((cfg.n_experts,), jnp.int32)
-    (x, tok, drp), storage = jax.lax.scan(body, (x, z, z),
-                                          (params["blocks"], storage))
+    storage, x, telemetry = _paged_layers(
+        params, cfg, rules, x, storage, scatter,
+        positions=start + jnp.arange(C), tables=table_row[None],
+        q_offset=start, use_pallas=use_pallas,
+        comm=_SERIAL if comm is None else comm, quant=quant,
+        ep_comm=ep_comm, placement=placement)
     x = L.rmsnorm(params["final_norm"], x, use_pallas=cfg.use_pallas)
-    return storage, x, {"expert_tokens": tok, "expert_dropped": drp}
+    return storage, x, telemetry
 
 
 def paged_decode_step(params, cfg, rules, storage, tables, lengths, tokens,
@@ -500,40 +556,19 @@ def paged_decode_step(params, cfg, rules, storage, tables, lengths, tokens,
     :func:`paged_prefill_chunk`.  ``quant`` quantizes each token's K/V on
     write (scales land in the storage's scale leaves).
 
+    The one-token window of :func:`paged_verify_chunk`: the stacked pool is
+    carried through the layer scan and layer ``l`` writes each slot's row
+    into its page ``write_pages`` in place.
+
     With a mesh ``comm`` (inside ``shard_map``) the unembed arrives
     vocab-sharded and the local logits are reassembled with a single tiled
     ``all_gather`` — the one collective at the logits head.
     """
-    from repro.serve import pages as PG
-    assert not uses_window_cache(cfg), "paged decode is global-attention only"
-    comm = _SERIAL if comm is None else comm
-    x = embed_tokens(params, tokens, cfg, rules)
-    positions = lengths[:, None]                                # (B, 1)
-
-    def write(kv, k, v):
-        return _write_kv(
-            kv, k[:, 0], v[:, 0], quant,
-            lambda st, val: PG.scatter_token(st, write_pages, write_offs,
-                                             val))
-
-    def body(carry, xs):
-        x, tok, drp = carry
-        p, kv = xs
-        x, kv, ms = _paged_block(p, x, cfg, rules, positions=positions,
-                                 kv=kv, tables=tables,
-                                 q_offset=lengths, write=write,
-                                 use_pallas=use_pallas, comm=comm,
-                                 ep_comm=ep_comm, placement=placement)
-        return (x, tok + ms["tokens"], drp + ms["dropped"]), kv
-
-    z = jnp.zeros((cfg.n_experts,), jnp.int32)
-    (x, tok, drp), storage = jax.lax.scan(body, (x, z, z),
-                                          (params["blocks"], storage))
-    with jax.named_scope("head"):
-        x = L.rmsnorm(params["final_norm"], x, use_pallas=cfg.use_pallas)
-        logits = comm.all_gather(lm_logits(params, x, cfg, rules),
-                                 axis=-1, tiled=True)
-    return storage, logits, {"expert_tokens": tok, "expert_dropped": drp}
+    return paged_verify_chunk(params, cfg, rules, storage, tables, lengths,
+                              tokens, write_pages[:, None],
+                              write_offs[:, None], use_pallas=use_pallas,
+                              comm=comm, quant=quant, ep_comm=ep_comm,
+                              placement=placement)
 
 
 def paged_verify_chunk(params, cfg, rules, storage, tables, lengths, tokens,
@@ -557,6 +592,10 @@ def paged_verify_chunk(params, cfg, rules, storage, tables, lengths, tokens,
     speculative acceptance rule scores draft i+1 against (and the
     correction/bonus is sampled from).  C == 1 is exactly a decode step.
 
+    The stacked pool is the layer scan's carry (:func:`_paged_layers`):
+    layer ``l`` scatters the window's rows into its pages ``write_pages`` in
+    place and attends to its pages of the stacked pool.
+
     Causality makes padding safe: query i attends keys <= lengths + i, and
     every real position's K/V is written (to its real page) before
     attention runs, while pad positions can only influence pad logits.
@@ -574,32 +613,20 @@ def paged_verify_chunk(params, cfg, rules, storage, tables, lengths, tokens,
     comm = _SERIAL if comm is None else comm
     x = embed_tokens(params, tokens, cfg, rules)
     C = x.shape[1]
-    positions = lengths[:, None] + jnp.arange(C)                # (B, C)
 
-    def write(kv, k, v):
-        return _write_kv(
-            kv, k, v, quant,
-            lambda st, val: PG.scatter_window(st, write_pages, write_offs,
-                                              val))
+    def scatter(st, val, off):
+        return PG.scatter_window(st, write_pages + off, write_offs, val)
 
-    def body(carry, xs):
-        x, tok, drp = carry
-        p, kv = xs
-        x, kv, ms = _paged_block(p, x, cfg, rules, positions=positions,
-                                 kv=kv, tables=tables,
-                                 q_offset=lengths, write=write,
-                                 use_pallas=use_pallas, comm=comm,
-                                 ep_comm=ep_comm, placement=placement)
-        return (x, tok + ms["tokens"], drp + ms["dropped"]), kv
-
-    z = jnp.zeros((cfg.n_experts,), jnp.int32)
-    (x, tok, drp), storage = jax.lax.scan(body, (x, z, z),
-                                          (params["blocks"], storage))
+    storage, x, telemetry = _paged_layers(
+        params, cfg, rules, x, storage, scatter,
+        positions=lengths[:, None] + jnp.arange(C), tables=tables,
+        q_offset=lengths, use_pallas=use_pallas, comm=comm, quant=quant,
+        ep_comm=ep_comm, placement=placement)
     with jax.named_scope("head"):
         x = L.rmsnorm(params["final_norm"], x, use_pallas=cfg.use_pallas)
         logits = comm.all_gather(lm_logits(params, x, cfg, rules),
                                  axis=-1, tiled=True)
-    return storage, logits, {"expert_tokens": tok, "expert_dropped": drp}
+    return storage, logits, telemetry
 
 
 def _window_decode_step(params, cfg, rules, cache, tokens, pos):

@@ -558,9 +558,15 @@ def scatter_chunk(storage, pages, chunk, *, page_size: int, n_prefix: int = 0):
     n = pages.shape[0]
     pre = chunk.shape[:n_prefix]
     suf = chunk.shape[n_prefix + 1:]
-    blk = chunk.reshape(pre + (n, page_size) + suf)
+    # written as a page of (token, head) rows, the layout the attention
+    # kernel reads: a scatter of narrow (Hkv, D) tiles (few KV heads, or
+    # one page, which becomes an update of a slice) has the TPU compiler
+    # lay the whole pool out anew around it
+    rows = (page_size * (suf[0] if suf else 1),) + suf[1:]
+    blk = chunk.reshape(pre + (n,) + rows)
     idx = _pfx(n_prefix) + (pages,)
-    return storage.at[idx].set(blk)
+    flat = storage.reshape(storage.shape[:n_prefix + 1] + rows)
+    return flat.at[idx].set(blk).reshape(storage.shape)
 
 
 def scatter_token(storage, pages, offs, vals, *, n_prefix: int = 0):

@@ -324,6 +324,35 @@ def test_paged_attention_mq_reads_live_pages_only(W, quantized):
     np.testing.assert_array_equal(got, clean)
 
 
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("D", [16, 128])
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["gather", "kernel"])
+def test_paged_attention_reads_one_layer_of_a_stacked_pool(use_pallas, D,
+                                                           quantized):
+    """With ``layer``, attention reads that layer's pages of a stacked (L,
+    N, ...) pool through the layer's own tables and scales: the same
+    output, bit for bit, as the layer's pool handed over alone (a head dim
+    of 16 is padded to lanes, 128 is not)."""
+    from repro.models.attention import paged_window_attention
+    L_, N, ps, Hkv, Hq, W, P = 3, 12, 8, 2, 4, 2, 5
+    q = _arr((3, W, Hq, D))
+    kp = _arr((L_, N, ps, Hkv, D))
+    vp = _arr((L_, N, ps, Hkv, D))
+    scales = {}
+    if quantized:
+        kp, vp, ks, vs = _int8_pages(kp, vp)
+        scales = dict(k_scale=ks[1], v_scale=vs[1])
+    tables = jnp.asarray(RNG.integers(0, N, size=(3, P)), jnp.int32)
+    n_cached = jnp.asarray([0, 9, P * ps - W], jnp.int32)
+    got = jax.jit(lambda k, v, layer: paged_window_attention(
+        q, k, v, tables, n_cached, layer=layer, use_pallas=use_pallas,
+        **scales))(kp, vp, jnp.int32(1))
+    want = paged_window_attention(q, kp[1], vp[1], tables, n_cached,
+                                  use_pallas=use_pallas, **scales)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_paged_attention_mq_w1_is_bitwise_decode():
     """W=1 must be the decode kernel, bit for bit — the engine's decode
     stream guarantees hang off this equivalence."""

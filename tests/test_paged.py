@@ -574,3 +574,145 @@ def test_paged_state_specs_match_pool_storage():
     spec_shapes = jax.tree_util.tree_map(
         lambda s: s.shape, specs, is_leaf=lambda x: hasattr(x, "spec"))
     assert shapes == spec_shapes
+
+
+# ---------------------------------------------------------------------------
+# The stacked pool through the layer scan: each program writes only its rows
+# ---------------------------------------------------------------------------
+
+PAGE, N_POOL, P_TABLE = 4, 12, 4           # pool: 12 pages + trash page 12
+
+
+def _phase_inputs(phase):
+    """One call's inputs for 3 slots (slot 2 dead) over tables of 4 pages:
+    the program's arguments and the (page, offset) rows it writes."""
+    trash = N_POOL
+    tables = np.array([[3, 7, 1, 9], [0, 4, 10, 2], [trash] * P_TABLE],
+                      np.int32)
+    if phase == "prefill":
+        start, C = 4, 8                    # pages 1 and 2 of slot 0's table
+        pages = tables[0, start // PAGE:(start + C) // PAGE]
+        tokens = np.arange(1, C + 1, dtype=np.int32)[None] * 7 % 500
+        rows = [(int(pg), o) for pg in pages for o in range(PAGE)]
+        return (tables[0], pages, np.int32(start), tokens), rows
+    lengths = np.array([5, 9, 0], np.int32)
+    C = 1 if phase == "decode" else 4
+    pos = lengths[:, None] + np.arange(C)
+    # slot 1's window ends in padding (positions past its 2 real tokens)
+    real = np.array([[True] * C, [True, True] + [False] * (C - 2)
+                     if C > 1 else [True], [False] * C])
+    wp = np.where(real, np.take_along_axis(tables, pos // PAGE, 1), trash)
+    wo = np.where(real, pos % PAGE, np.arange(C) % PAGE)
+    tokens = (np.arange(3 * C, dtype=np.int32).reshape(3, C) * 11 + 3) % 500
+    rows = sorted({(int(p), int(o)) for p, o in zip(wp.ravel(), wo.ravel())})
+    if phase == "decode":
+        wp, wo = wp[:, 0], wo[:, 0]
+    return (tables, lengths, tokens, wp.astype(np.int32),
+            wo.astype(np.int32)), rows
+
+
+def _random_pool(model, quant, key):
+    """Pool storage filled with random values, so a stray write shows."""
+    specs = model.paged_state_specs(N_POOL, PAGE, quant)
+    leaves, tree = jax.tree_util.tree_flatten(
+        specs, is_leaf=lambda x: hasattr(x, "spec"))
+    out = []
+    for i, s in enumerate(leaves):
+        k = jax.random.fold_in(key, i)
+        if jnp.issubdtype(s.dtype, jnp.integer):
+            out.append(jax.random.randint(k, s.shape, -127, 128, s.dtype))
+        else:
+            out.append(jax.random.uniform(k, s.shape, s.dtype, 0.5, 1.5))
+    return jax.tree_util.tree_unflatten(tree, out)
+
+
+def _per_layer_slice_program(model, phase, params, storage, args, quant,
+                             use_pallas):
+    """The paged program with the pool as the layer scan's xs/ys: each
+    layer's slice of the pool goes in, is written and comes back out."""
+    from repro.models import layers as L
+    from repro.models import transformer as T
+    cfg = model.cfg
+    if phase == "prefill":
+        row, pages, start, tokens = args
+        C = tokens.shape[1]
+        positions, tables, q_offset = start + jnp.arange(C), row[None], start
+
+        def scatter(st, val):
+            return PG.scatter_chunk(st, pages, val[0], page_size=PAGE)
+    else:
+        tables, lengths, tokens, wp, wo = args
+        C = tokens.shape[1]
+        positions, q_offset = lengths[:, None] + jnp.arange(C), lengths
+        if phase == "decode":
+            def scatter(st, val):
+                return PG.scatter_token(st, wp, wo, val[:, 0])
+        else:
+            def scatter(st, val):
+                return PG.scatter_window(st, wp, wo, val)
+
+    def write(kv, k, v):
+        return T._write_kv(kv, k, v, quant, scatter)
+
+    def body(carry, xs):
+        x, tok, drp = carry
+        p, kv = xs
+        x, kv, ms = T._paged_block(p, x, cfg, None, positions=positions,
+                                   kv=kv, tables=tables, q_offset=q_offset,
+                                   write=write, use_pallas=use_pallas)
+        return (x, tok + ms["tokens"], drp + ms["dropped"]), kv
+
+    z = jnp.zeros((cfg.n_experts,), jnp.int32)
+    x = T.embed_tokens(params, tokens, cfg, None)
+    (x, tok, drp), storage = jax.lax.scan(body, (x, z, z),
+                                          (params["blocks"], storage))
+    x = L.rmsnorm(params["final_norm"], x, use_pallas=cfg.use_pallas)
+    if phase != "prefill":
+        x = T.lm_logits(params, x, cfg, None)
+    return storage, x, {"expert_tokens": tok, "expert_dropped": drp}
+
+
+@pytest.mark.parametrize("kv_quant", ["off", "int8"])
+@pytest.mark.parametrize("phase", ["decode", "verify", "prefill"])
+@pytest.mark.parametrize("attention,head_dim", [
+    ("gather", 16), ("kernel", 128), ("kernel", 16)],
+    ids=["gather", "kernel", "kernel-narrow-head"])
+def test_paged_program_writes_only_its_rows(phase, kv_quant, attention,
+                                            head_dim):
+    """One call of a paged program changes only the (layer, page, offset)
+    rows it targets, in every layer (the layer's own trash page for dead
+    and pad positions); every other row of every layer stays bitwise the
+    same.  Its outputs equal the per-layer-slice formulation's, bit for
+    bit: with the gather, and with the kernel reading the stacked pool at
+    head_dim 128 and at head_dim 16 (which the kernel pads to lanes)."""
+    from repro.serve.quant import make_kv_quant
+    cfg = smoke_config("qwen2-7b").replace(remat="none", n_layers=3,
+                                           head_dim=head_dim)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    quant = make_kv_quant(kv_quant)
+    use_pallas = attention == "kernel"
+    args, rows = _phase_inputs(phase)
+    args = tuple(jnp.asarray(a) for a in args)
+    before = _random_pool(model, quant, jax.random.PRNGKey(1))
+    program = {"decode": model.paged_decode_step,
+               "verify": model.paged_verify,
+               "prefill": model.paged_prefill_chunk}[phase]
+    got = jax.jit(lambda p, st, *a: program(
+        p, st, *a, None, use_pallas=use_pallas, quant=quant))(
+        params, before, *args)
+    want = jax.jit(lambda p, st, *a: _per_layer_slice_program(
+        model, phase, p, st, a, quant, use_pallas))(params, before, *args)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, got, want)
+
+    pages, offs = (np.array(c) for c in zip(*rows))
+    for name, leaf in got[0].items():
+        new, old = np.asarray(leaf), np.asarray(before[name])
+        assert new.shape[:2] == (cfg.n_layers, N_POOL + 1), name
+        changed = np.any((new != old).reshape(new.shape[:3] + (-1,)), -1)
+        target = np.zeros_like(changed)
+        target[:, pages, offs] = True
+        assert not np.any(changed & ~target), (name, np.argwhere(changed
+                                                                 & ~target))
+        # every layer wrote its own rows (the trash page's too)
+        assert np.all(changed[:, pages, offs]), name
