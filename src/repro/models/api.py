@@ -191,24 +191,17 @@ class Model:
         change NOTHING for text-only families."""
         raise NotImplementedError(f"{self.cfg.family} has no paged KV cache")
 
-    def paged_decode_step(self, params, storage, tables, lengths, tokens,
-                          write_pages, write_offs, rules, *,
-                          use_pallas: bool = False, comm=None, quant=None,
-                          ep_comm=None, placement=None, cross=None):
-        """tokens (B,1) -> (new_storage, logits (B,1,V), moe telemetry)."""
-        raise NotImplementedError(f"{self.cfg.family} has no paged KV cache")
-
     def paged_verify(self, params, storage, tables, lengths, tokens,
                      write_pages, write_offs, rules, *,
                      use_pallas: bool = False, comm=None, quant=None,
                      ep_comm=None, placement=None, cross=None):
-        """Speculative-decode verify: score a (B, C) window of candidate
-        tokens per slot in one batched forward (position 0 = the next
-        input, 1..C-1 = drafts).  ``write_pages``/``write_offs`` are
-        (B, C) per-position K/V targets (pads -> trash page).  Returns
-        (new_storage, logits (B, C, V)).  Families without a paged KV
-        cache fall back to per-token decode (the engine never calls this
-        for them)."""
+        """Score a (B, C) window of candidate tokens per slot in one
+        batched forward (position 0 = the next input, 1..C-1 = drafts):
+        the speculative-decode verify, and at C == 1 the decode step.
+        ``write_pages``/``write_offs`` are (B, C) per-position K/V targets
+        (pads and dead slots -> trash page).  Returns (new_storage, logits
+        (B, C, V), moe telemetry).  Families without a paged KV cache
+        decode on the dense path (the engine never calls this for them)."""
         raise NotImplementedError(f"{self.cfg.family} has no paged KV cache")
 
     # -- serving-mesh sharding rules -----------------------------------------
@@ -289,10 +282,6 @@ class Model:
                 f"{cfg.name}: serving mesh (tp={tp}, ep={ep}) does not "
                 "divide " + ", ".join(bad))
 
-    def validate_serve_tp(self, tp: int) -> None:
-        """Back-compat alias for :meth:`validate_serve_mesh` (1-D mesh)."""
-        self.validate_serve_mesh(tp=tp)
-
     def validate_serve_encoder(self, *, page_size: int, max_len: int,
                                prefix_cache: bool = False) -> None:
         """Raise (with the fix spelled out) when the family's encoder
@@ -312,7 +301,8 @@ class Model:
 
 class DecoderLM(Model):
     """Decoder-only transformer LM (dense or MoE): the full paged-serving
-    protocol — prefill chunks, single-token decode, spec-decode verify."""
+    protocol — prefill chunks and verify windows (decode is the one-token
+    window)."""
 
     def param_defs(self):
         return T.transformer_defs(self.cfg)
@@ -386,17 +376,6 @@ class DecoderLM(Model):
                                      use_pallas=use_pallas, comm=comm,
                                      quant=quant, ep_comm=ep_comm,
                                      placement=placement, embeds=embeds)
-
-    def paged_decode_step(self, params, storage, tables, lengths, tokens,
-                          write_pages, write_offs, rules, *,
-                          use_pallas: bool = False, comm=None, quant=None,
-                          ep_comm=None, placement=None, cross=None):
-        assert cross is None, "decoder-only families have no cross-KV pages"
-        return T.paged_decode_step(params, self.cfg, rules, storage, tables,
-                                   lengths, tokens, write_pages, write_offs,
-                                   use_pallas=use_pallas, comm=comm,
-                                   quant=quant, ep_comm=ep_comm,
-                                   placement=placement)
 
     def paged_verify(self, params, storage, tables, lengths, tokens,
                      write_pages, write_offs, rules, *,
@@ -686,16 +665,6 @@ class Whisper(Model):
             params, self.cfg, rules, storage, table_row, pages_chunk, start,
             tokens, cross["storage"], cross["tables"], cross["frames_len"],
             use_pallas=use_pallas, quant=quant)
-
-    def paged_decode_step(self, params, storage, tables, lengths, tokens,
-                          write_pages, write_offs, rules, *,
-                          use_pallas: bool = False, comm=None, quant=None,
-                          ep_comm=None, placement=None, cross=None):
-        assert cross is not None, "enc-dec decode needs cross-KV pages"
-        return W.paged_decode_step(
-            params, self.cfg, rules, storage, tables, lengths, tokens,
-            write_pages, write_offs, cross["storage"], cross["tables"],
-            cross["frames_len"], use_pallas=use_pallas, quant=quant)
 
     def paged_verify(self, params, storage, tables, lengths, tokens,
                      write_pages, write_offs, rules, *,

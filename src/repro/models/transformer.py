@@ -544,38 +544,11 @@ def paged_prefill_chunk(params, cfg, rules, storage, table_row, pages_chunk,
     return storage, x, telemetry
 
 
-def paged_decode_step(params, cfg, rules, storage, tables, lengths, tokens,
-                      write_pages, write_offs, use_pallas=False,
-                      comm=None, quant=None, ep_comm=None, placement=None):
-    """One token for every slot against paged storage.
-
-    tokens: (B, 1);  tables: (B, P);  lengths: (B,) tokens already cached
-    (= the current token's position);  write_pages/write_offs: (B,) where
-    each slot's new K/V lands (dead slots point at the pool's trash page).
-    Returns (storage, logits (B, 1, V), telemetry) — telemetry as in
-    :func:`paged_prefill_chunk`.  ``quant`` quantizes each token's K/V on
-    write (scales land in the storage's scale leaves).
-
-    The one-token window of :func:`paged_verify_chunk`: the stacked pool is
-    carried through the layer scan and layer ``l`` writes each slot's row
-    into its page ``write_pages`` in place.
-
-    With a mesh ``comm`` (inside ``shard_map``) the unembed arrives
-    vocab-sharded and the local logits are reassembled with a single tiled
-    ``all_gather`` — the one collective at the logits head.
-    """
-    return paged_verify_chunk(params, cfg, rules, storage, tables, lengths,
-                              tokens, write_pages[:, None],
-                              write_offs[:, None], use_pallas=use_pallas,
-                              comm=comm, quant=quant, ep_comm=ep_comm,
-                              placement=placement)
-
-
 def paged_verify_chunk(params, cfg, rules, storage, tables, lengths, tokens,
                        write_pages, write_offs, use_pallas=False, comm=None,
                        quant=None, ep_comm=None, placement=None):
     """Score a per-slot window of candidate tokens in ONE batched forward —
-    the speculative-decode verify step.
+    the speculative-decode verify step, and at C == 1 the decode step.
 
     tokens: (B, C) — position 0 is each slot's next input token (its K/V is
     not yet cached), positions 1..C-1 are draft continuations (right-padded
@@ -590,7 +563,9 @@ def paged_verify_chunk(params, cfg, rules, storage, tables, lengths, tokens,
     :func:`paged_prefill_chunk`): logits[:, i] is the target
     distribution for the token FOLLOWING tokens[:, i] — what the
     speculative acceptance rule scores draft i+1 against (and the
-    correction/bonus is sampled from).  C == 1 is exactly a decode step.
+    correction/bonus is sampled from).  C == 1 is the decode step.
+    ``quant`` quantizes each written row's K/V (scales land in the
+    storage's scale leaves).
 
     The stacked pool is the layer scan's carry (:func:`_paged_layers`):
     layer ``l`` scatters the window's rows into its pages ``write_pages`` in
@@ -603,10 +578,10 @@ def paged_verify_chunk(params, cfg, rules, storage, tables, lengths, tokens,
     kernel (same per-row causal rule), keeping spec-on/spec-off greedy
     bit-parity intact.
 
-    With a mesh ``comm`` (inside ``shard_map``) this is sharded exactly
-    like :func:`paged_decode_step`: params/storage head-sharded, one psum
-    after each residual projection, one tiled all_gather at the logits
-    head.
+    With a mesh ``comm`` (inside ``shard_map``): params/storage
+    head-sharded, one psum after each residual projection; the unembed
+    arrives vocab-sharded and the local logits are reassembled with a
+    single tiled ``all_gather`` — the one collective at the logits head.
     """
     from repro.serve import pages as PG
     assert not uses_window_cache(cfg), "paged decode is global-attention only"

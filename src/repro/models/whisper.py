@@ -394,48 +394,14 @@ def paged_prefill_chunk(params, cfg, rules, storage, table_row, pages_chunk,
     return storage, x, _no_moe()
 
 
-def paged_decode_step(params, cfg, rules, storage, tables, lengths, tokens,
-                      write_pages, write_offs, cross_storage, cross_tables,
-                      frames_len, use_pallas=False, quant=None):
-    """One decode token per slot with a cross-attention read.
-
-    Self side matches :func:`repro.models.transformer.paged_decode_step`;
-    ``cross_tables`` (B, Pc) and ``frames_len`` (B,) add the per-slot cross
-    read (dead slots: trash page + 0 frames).  Returns (storage, logits
-    (B, 1, V), telemetry).
-    """
-    from repro.serve import pages as PG
-    x = L.embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
-    x = x + _pos_embed(lengths[:, None], cfg.d_model).astype(x.dtype)
-
-    def write(kv, k, v):
-        return T._write_kv(
-            kv, k[:, 0], v[:, 0], quant,
-            lambda st, val: PG.scatter_token(st, write_pages, write_offs,
-                                             val))
-
-    def body(x, xs):
-        p, kv, ckv = xs
-        x, kv = _paged_dec_block(p, x, cfg, kv=kv, tables=tables,
-                                 q_offset=lengths, write=write,
-                                 cross_kv=ckv, cross_tables=cross_tables,
-                                 frames_len=frames_len,
-                                 use_pallas=use_pallas)
-        return x, kv
-
-    x, storage = jax.lax.scan(body, x, (params["dec_blocks"], storage,
-                                        cross_storage))
-    x = L.layernorm(params["dec_norm"], x)
-    logits = T.lm_logits(params, x, cfg, rules)
-    return storage, logits, _no_moe()
-
-
 def paged_verify_chunk(params, cfg, rules, storage, tables, lengths, tokens,
                        write_pages, write_offs, cross_storage, cross_tables,
                        frames_len, use_pallas=False, quant=None):
     """Score a (B, C) candidate window in one forward (speculative verify)
     — :func:`repro.models.transformer.paged_verify_chunk` plus the cross
-    read.  C == 1 is exactly a decode step."""
+    read.  C == 1 is the decode step.  ``cross_tables`` (B, Pc) and
+    ``frames_len`` (B,) give each slot's cross read (dead slots: trash page
+    + 0 frames).  Returns (storage, logits (B, C, V), telemetry)."""
     from repro.serve import pages as PG
     x = L.embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
     C = x.shape[1]

@@ -30,11 +30,15 @@ Layering (see README "Serving architecture"):
   rejection-samples without changing the target distribution), and roll
   over-reserved pages back to the pool.
 * this module — pure execution: jitted device calls driven by the
-  scheduler's plan.  ``paged_decode_step`` writes each slot's token K/V
-  through (page, offset) targets and attends through the page table
-  (Pallas kernel :mod:`repro.kernels.paged_attention` on a TPU, jnp
-  gather elsewhere); dead slots write to the pool's trash page so the SPMD tick
-  keeps static shapes.
+  scheduler's plan, each named program built by one helper in one
+  execution context (weight transform, attention kernel, KV quantization,
+  mesh).  The paged phases are the prefill chunk (``paged_prefill_chunk``)
+  and the verify window (``paged_verify``), whose one-token case is the
+  decode step: each writes its tokens' K/V through (page, offset) targets
+  and attends through the page table (Pallas kernel
+  :mod:`repro.kernels.paged_attention` on a TPU, jnp gather elsewhere);
+  dead slots write to the pool's trash page so the SPMD tick keeps static
+  shapes.
 
 Families whose decode state is per-token KV (dense / MoE / VLM stacked
 caches) run paged; recurrent-state families (rwkv6, mamba2/zamba) and
@@ -344,6 +348,7 @@ class ServeEngine:
         # scheduler and page tables stay host-side and replicated either way.
         self.mesh = mesh
         self._param_shardings = None
+        pspecs = None
         if mesh is not None:
             if rules is not None:
                 raise ValueError(
@@ -456,156 +461,96 @@ class ServeEngine:
                       "expert_imbalance": 0.0, "placement_updates": 0,
                       "decode_live_pages": 0, "decode_table_pages": 0}
 
-        # donate the state/storage argument so XLA updates the KV buffers in
-        # place (no full-pool copy per tick)
-        donate = (1,)
+        # -- the engine's programs -------------------------------------------
+        # One execution context for every program that takes (params, state,
+        # *inputs): the weight transform, the attention kernel, the KV
+        # quantization and, on a mesh, the collectives (``rules`` is None
+        # there).  ``program`` builds each of them.
         rep = P()
+        ctx = dict(use_pallas=self.use_pallas_attention, quant=kvq,
+                   comm=None, ep_comm=None)
+        if mesh is not None:
+            ctx.update(comm=Comm("model"),
+                       ep_comm=Comm("expert")
+                       if "expert" in mesh.axis_names else None)
+
+        def program(name, call, in_specs, out_specs):
+            """The program ``jit_<name>`` of ``call(params, state,
+            *inputs)``, the weight transform applied to the params and the
+            state donated, so that XLA updates the KV buffers in place (no
+            full-pool copy per tick).  On a mesh the body runs under
+            ``shard_map``, the params sharded by ``pspecs`` and the state
+            and inputs by ``in_specs``."""
+            return _jit(name, lambda p, *a: call(deq(p), *a), donate=(1,),
+                        mesh=mesh, in_specs=(pspecs,) + in_specs,
+                        out_specs=out_specs)
+
         if self.paged:
             if num_pages is None:       # dense-equivalent budget by default
                 num_pages = -(-max_slots * max_len // page_size)
-            if mesh is None:
-                self.pool = PagePool(model.paged_leaf_specs(kvq),
-                                     num_pages=num_pages, page_size=page_size,
-                                     prefix_cache=self.prefix_cache)
-                self._cow_copy = _jit(
-                    "cow_copy",
-                    lambda st, s, d: PG.copy_pages(st, self.pool.leaf_specs,
-                                                   s, d),
-                    donate=(0,))
-                self._decode_paged = _jit(
-                    "decode_paged",
-                    lambda p, st, tb, ln, t, wp, wo, pl:
-                    model.paged_decode_step(
-                        deq(p), st, tb, ln, t, wp, wo, rules,
-                        use_pallas=use_pallas_attention, quant=kvq,
-                        placement=pl),
-                    donate=donate)
-                self._prefill_chunk = _jit(
-                    "prefill_chunk",
-                    lambda p, st, row, pg, s0, t, pl:
-                    model.paged_prefill_chunk(
-                        deq(p), st, row, pg, s0, t, rules,
-                        use_pallas=use_pallas_attention, quant=kvq,
-                        placement=pl),
-                    donate=donate)
-                self._verify_paged = _jit(
-                    "verify_paged",
-                    lambda p, st, tb, ln, t, wp, wo, pl: model.paged_verify(
-                        deq(p), st, tb, ln, t, wp, wo, rules,
-                        use_pallas=use_pallas_attention, quant=kvq,
-                        placement=pl),
-                    donate=donate)
-                if self._n_image:
-                    # separate jit so the embeds-free path stays byte-
-                    # identical to the text-only engine (same program)
-                    self._prefill_chunk_embeds = _jit(
-                        "prefill_chunk_embeds",
-                        lambda p, st, row, pg, s0, t, em, pl:
-                        model.paged_prefill_chunk(
-                            deq(p), st, row, pg, s0, t, rules,
-                            use_pallas=use_pallas_attention, quant=kvq,
-                            placement=pl, embeds=em),
-                        donate=donate)
-                if self._enc_dec:
-                    # cross storage is READ-ONLY in these calls and not
-                    # returned, so it must NOT be donated (donation would
-                    # delete the live buffers); only the self-KV storage
-                    # (argnum 1) is donated as usual
-                    self._decode_paged = _jit(
-                        "decode_paged",
-                        lambda p, st, tb, ln, t, wp, wo, pl, cst, ctb, fl:
-                        model.paged_decode_step(
-                            deq(p), st, tb, ln, t, wp, wo, rules,
-                            use_pallas=use_pallas_attention, quant=kvq,
-                            placement=pl,
-                            cross=dict(storage=cst, tables=ctb,
-                                       frames_len=fl)),
-                        donate=donate)
-                    self._prefill_chunk = _jit(
-                        "prefill_chunk",
-                        lambda p, st, row, pg, s0, t, pl, cst, ctb, fl:
-                        model.paged_prefill_chunk(
-                            deq(p), st, row, pg, s0, t, rules,
-                            use_pallas=use_pallas_attention, quant=kvq,
-                            placement=pl,
-                            cross=dict(storage=cst, tables=ctb,
-                                       frames_len=fl)),
-                        donate=donate)
-                    self._verify_paged = _jit(
-                        "verify_paged",
-                        lambda p, st, tb, ln, t, wp, wo, pl, cst, ctb, fl:
-                        model.paged_verify(
-                            deq(p), st, tb, ln, t, wp, wo, rules,
-                            use_pallas=use_pallas_attention, quant=kvq,
-                            placement=pl,
-                            cross=dict(storage=cst, tables=ctb,
-                                       frames_len=fl)),
-                        donate=donate)
-            else:
+            sspecs = shardings = None
+            if mesh is not None:
                 sspecs = model.paged_storage_specs(kvq)
-                self.pool = PagePool(
-                    model.paged_leaf_specs(kvq), num_pages=num_pages,
-                    page_size=page_size,
-                    shardings=jax.tree_util.tree_map(
-                        lambda s: NamedSharding(mesh, s), sspecs,
-                        is_leaf=lambda x: isinstance(x, P)),
-                    prefix_cache=self.prefix_cache)
-                comm = Comm("model")
-                ep_comm = Comm("expert") if "expert" in mesh.axis_names \
-                    else None
-                # COW copies move whole pages along the (replicated) page
-                # axis — each shard copies its local heads independently
-                self._cow_copy = _jit(
-                    "cow_copy",
-                    lambda st, s, d: PG.copy_pages(st, self.pool.leaf_specs,
-                                                   s, d),
-                    donate=(0,), mesh=mesh, in_specs=(sspecs, rep, rep),
-                    out_specs=sspecs)
-                self._decode_paged = _jit(
-                    "decode_paged",
-                    lambda p, st, tb, ln, t, wp, wo, pl:
-                    model.paged_decode_step(
-                        deq(p), st, tb, ln, t, wp, wo, None,
-                        use_pallas=use_pallas_attention, comm=comm,
-                        quant=kvq, ep_comm=ep_comm, placement=pl),
-                    donate=donate, mesh=mesh,
-                    in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep, rep),
-                    out_specs=(sspecs, rep, rep))
-                self._prefill_chunk = _jit(
-                    "prefill_chunk",
-                    lambda p, st, row, pg, s0, t, pl:
-                    model.paged_prefill_chunk(
-                        deq(p), st, row, pg, s0, t, None,
-                        use_pallas=use_pallas_attention, comm=comm,
-                        quant=kvq, ep_comm=ep_comm, placement=pl),
-                    donate=donate, mesh=mesh,
-                    in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep),
-                    out_specs=(sspecs, rep, rep))
-                self._verify_paged = _jit(
-                    "verify_paged",
-                    lambda p, st, tb, ln, t, wp, wo, pl: model.paged_verify(
-                        deq(p), st, tb, ln, t, wp, wo, None,
-                        use_pallas=use_pallas_attention, comm=comm,
-                        quant=kvq, ep_comm=ep_comm, placement=pl),
-                    donate=donate, mesh=mesh,
-                    in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep, rep),
-                    out_specs=(sspecs, rep, rep))
-                if self._n_image:
-                    # image embeds replicate like the token chunk (the
-                    # prefix rides the replicated activation path; heads
-                    # shard inside the model as usual)
-                    self._prefill_chunk_embeds = _jit(
-                        "prefill_chunk_embeds",
-                        lambda p, st, row, pg, s0, t, em, pl:
-                        model.paged_prefill_chunk(
-                            deq(p), st, row, pg, s0, t, None,
-                            use_pallas=use_pallas_attention, comm=comm,
-                            quant=kvq, ep_comm=ep_comm, placement=pl,
-                            embeds=em),
-                        donate=donate, mesh=mesh,
-                        in_specs=(pspecs, sspecs, rep, rep, rep, rep, rep,
-                                  rep),
-                        out_specs=(sspecs, rep, rep))
+                shardings = jax.tree_util.tree_map(
+                    lambda s: NamedSharding(mesh, s), sspecs,
+                    is_leaf=lambda x: isinstance(x, P))
+            self.pool = PagePool(model.paged_leaf_specs(kvq),
+                                 num_pages=num_pages, page_size=page_size,
+                                 shardings=shardings,
+                                 prefix_cache=self.prefix_cache)
+            # COW copies move whole pages along the (replicated) page axis —
+            # on a mesh each shard copies its local heads independently
+            self._cow_copy = _jit(
+                "cow_copy",
+                lambda st, s, d: PG.copy_pages(st, self.pool.leaf_specs, s, d),
+                donate=(0,), mesh=mesh, in_specs=(sspecs, rep, rep),
+                out_specs=sspecs)
+
+            # The paged programs: (params, storage, *inputs, placement map,
+            # *extras) -> (storage, output, MoE telemetry), every input
+            # replicated on a mesh.  Enc-dec's extras are its read-only
+            # cross-KV storage (not donated: it is not returned, and
+            # donating would delete the live buffers), page tables and valid
+            # frames.  Decode is the verify window one token wide.
+            def paged_specs(n_in):
+                return (sspecs,) + (rep,) * n_in, (sspecs, rep, rep)
+
+            n_x = 3 if self._enc_dec else 0
+
+            def extras(*x):
+                if not x:
+                    return {}
+                cst, ctb, fl = x
+                return {"cross": dict(storage=cst, tables=ctb, frames_len=fl)}
+
+            self._decode_paged = program(
+                "decode_paged",
+                lambda p, st, tb, ln, t, wp, wo, pl, *x: model.paged_verify(
+                    p, st, tb, ln, t, wp[:, None], wo[:, None], rules,
+                    placement=pl, **ctx, **extras(*x)),
+                *paged_specs(6 + n_x))
+            self._verify_paged = program(
+                "verify_paged",
+                lambda p, st, tb, ln, t, wp, wo, pl, *x: model.paged_verify(
+                    p, st, tb, ln, t, wp, wo, rules, placement=pl, **ctx,
+                    **extras(*x)),
+                *paged_specs(6 + n_x))
+            self._prefill_chunk = program(
+                "prefill_chunk",
+                lambda p, st, row, pg, s0, t, pl, *x:
+                model.paged_prefill_chunk(p, st, row, pg, s0, t, rules,
+                                          placement=pl, **ctx, **extras(*x)),
+                *paged_specs(5 + n_x))
+            if self._n_image:
+                # a program of its own, so that the embeds-free prefill
+                # stays the text-only engine's program; the embeds replicate
+                # like the token chunk (heads shard inside the model)
+                self._prefill_chunk_embeds = program(
+                    "prefill_chunk_embeds",
+                    lambda p, st, row, pg, s0, t, pl, em:
+                    model.paged_prefill_chunk(p, st, row, pg, s0, t, rules,
+                                              placement=pl, embeds=em, **ctx),
+                    *paged_specs(6))
             cross_kw = {}
             if self._enc_dec:
                 # one read-only cross-KV pool sized for every slot holding
@@ -639,16 +584,10 @@ class ServeEngine:
         else:
             self.pool = None
             self.sched = Scheduler(max_slots=max_slots, max_len=max_len)
-            if mesh is None:
-                self._fresh_state = lambda: model.init_decode_state(
-                    max_slots, max_len)
-                self.state = self._fresh_state()
-                self._decode = _jit(
-                    "decode",
-                    lambda p, s, t, pos: model.decode_step(p, s, t, pos,
-                                                           rules),
-                    donate=donate)
-            else:
+            st_specs = None
+            self._fresh_state = lambda: model.init_decode_state(
+                max_slots, max_len)
+            if mesh is not None:
                 if max_slots % self.tp:
                     raise ValueError(
                         f"slot-parallel serving shards slots over the mesh: "
@@ -659,14 +598,13 @@ class ServeEngine:
                     is_leaf=lambda x: isinstance(x, P))
                 self._fresh_state = lambda: jax.device_put(
                     model.init_decode_state(max_slots, max_len), st_sh)
-                self.state = self._fresh_state()
-                self._decode = _jit(
-                    "decode",
-                    lambda p, s, t, pos: model.decode_step(p, s, t, pos,
-                                                           None),
-                    donate=donate, mesh=mesh,
-                    in_specs=(pspecs, st_specs, P("model", None), P("model")),
-                    out_specs=(st_specs, P("model", None, None)))
+            self.state = self._fresh_state()
+            # slot-parallel on a mesh: each shard decodes its own slots
+            self._decode = program(
+                "decode",
+                lambda p, s, t, pos: model.decode_step(p, s, t, pos, rules),
+                (st_specs, P("model", None), P("model")),
+                (st_specs, P("model", None, None)))
             self._prefill = _jit(
                 "prefill", lambda p, b: model.prefill(p, b, rules, max_len))
 
@@ -1053,28 +991,17 @@ class ServeEngine:
             with _span("serve.prefill", rid=job.req.rid, start=job.start,
                        n=job.n_valid):
                 try:
+                    prefill, extras = self._prefill_chunk, \
+                        self._cross_inputs(slot=job.slot)
                     if job.embeds is not None:
-                        storage, hidden, tel = self._prefill_chunk_embeds(
-                            self.params, self.pool.storage,
-                            jnp.asarray(self.sched.table[job.slot]),
-                            jnp.asarray(job.pages), np.int32(job.start),
-                            jnp.asarray(job.tokens[None]),
-                            jnp.asarray(job.embeds[None]), self._place_arr)
-                    elif self._enc_dec:
-                        storage, hidden, tel = self._prefill_chunk(
-                            self.params, self.pool.storage,
-                            jnp.asarray(self.sched.table[job.slot]),
-                            jnp.asarray(job.pages), np.int32(job.start),
-                            jnp.asarray(job.tokens[None]), self._place_arr,
-                            self.cross_pool.storage,
-                            jnp.asarray(self.sched.cross_table[job.slot]),
-                            np.int32(self.sched.enc_total[job.slot]))
-                    else:
-                        storage, hidden, tel = self._prefill_chunk(
-                            self.params, self.pool.storage,
-                            jnp.asarray(self.sched.table[job.slot]),
-                            jnp.asarray(job.pages), np.int32(job.start),
-                            jnp.asarray(job.tokens[None]), self._place_arr)
+                        prefill, extras = self._prefill_chunk_embeds, \
+                            (jnp.asarray(job.embeds[None]),)
+                    storage, hidden, tel = prefill(
+                        self.params, self.pool.storage,
+                        jnp.asarray(self.sched.table[job.slot]),
+                        jnp.asarray(job.pages), np.int32(job.start),
+                        jnp.asarray(job.tokens[None]), self._place_arr,
+                        *extras)
                     self.pool.storage = storage
                     self._account_moe(tel)
                     self.sched.chunk_done(job)
@@ -1214,22 +1141,33 @@ class ServeEngine:
             wpages, woffs = wpages[:, 0], woffs[:, 0]
         args = (jnp.asarray(self.sched.table), jnp.asarray(lens),
                 jnp.asarray(toks), jnp.asarray(wpages), jnp.asarray(woffs),
-                self._place_arr)
-        if self._enc_dec:
-            # dead slots keep frames_len=0: every cross read is fully
-            # masked (attention renormalizes to zeros), so their stale
-            # table rows are never observable
-            cflens = np.zeros(B, np.int32)
-            for slot in live:
-                cflens[slot] = self.sched.enc_total[slot]
-            args += (self.cross_pool.storage,
-                     jnp.asarray(self.sched.cross_table), jnp.asarray(cflens))
+                self._place_arr) + self._cross_inputs(live=live)
         cow_args = (jnp.asarray([a for _, a, _ in cow], jnp.int32),
                     jnp.asarray([b for _, _, b in cow], jnp.int32)) \
             if cow else ()
         self.stats["decode_live_pages"] += live_pages
         self.stats["decode_table_pages"] += self.sched.table.size
         return args, cow_args, live_pages
+
+    def _cross_inputs(self, *, slot=None, live=()) -> tuple:
+        """An enc-dec program's trailing inputs: the read-only cross-KV
+        storage, then the page table and valid audio frames of ``slot``
+        (a prefill chunk) or of every slot (a decode or verify step, with
+        ``live`` its live slots).  Other families have none."""
+        if not self._enc_dec:
+            return ()
+        if slot is not None:
+            return (self.cross_pool.storage,
+                    jnp.asarray(self.sched.cross_table[slot]),
+                    np.int32(self.sched.enc_total[slot]))
+        # dead slots keep frames_len=0: every cross read is fully masked
+        # (attention renormalizes to zeros), so their stale table rows are
+        # never observable
+        frames = np.zeros(self.max_slots, np.int32)
+        for s in live:
+            frames[s] = self.sched.enc_total[s]
+        return (self.cross_pool.storage, jnp.asarray(self.sched.cross_table),
+                jnp.asarray(frames))
 
     # -- expert telemetry + load-aware placement -----------------------------
 

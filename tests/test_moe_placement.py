@@ -1,8 +1,8 @@
 """Expert capacity, mesh validation and load-aware placement (host-side).
 
 Single-device tier-1 coverage for the expert-parallel serving stack:
-``capacity()`` edge cases, ``validate_serve_mesh`` (+ the ``validate_serve_tp``
-alias), the ``plan_placement`` rebalancer (skew gains, hot-expert replication,
+``capacity()`` edge cases, ``validate_serve_mesh`` (1-D and 2-D meshes),
+the ``plan_placement`` rebalancer (skew gains, hot-expert replication,
 zero-traffic eviction, determinism), ``apply_placement`` as a pure weight
 permutation, the replicated-combine == single-copy bitwise property, and
 engine-level drop telemetry + placement stream parity.  Multi-device parity
@@ -39,7 +39,7 @@ def test_capacity_edge_cases():
 
 def test_validate_serve_mesh_and_alias():
     """Every indivisible dimension is named; dense families refuse an
-    expert axis outright; the old validate_serve_tp name still works."""
+    expert axis outright; ``tp`` alone validates a 1-D ("model",) mesh."""
     dense = build_model(smoke_config("qwen2-7b"))      # hq=4, hkv=2
     moe = build_model(smoke_config("qwen3-moe-235b-a22b"))  # E=8
 
@@ -54,10 +54,10 @@ def test_validate_serve_mesh_and_alias():
         moe.validate_serve_mesh(tp=2, ep=8)            # ep*tp = 16 > 8
     with pytest.raises(ValueError, match="dense family"):
         dense.validate_serve_mesh(tp=1, ep=2)
-    # the legacy entry point is an alias for ep=1
-    dense.validate_serve_tp(2)
+    # a 1-D mesh: ep keeps its default of 1
+    dense.validate_serve_mesh(tp=2)
     with pytest.raises(ValueError, match="padded_kv_heads=2"):
-        dense.validate_serve_tp(4)
+        dense.validate_serve_mesh(tp=4)
 
 
 def test_plan_placement_skew_gain_and_determinism():

@@ -695,9 +695,14 @@ def test_paged_program_writes_only_its_rows(phase, kv_quant, attention,
     args, rows = _phase_inputs(phase)
     args = tuple(jnp.asarray(a) for a in args)
     before = _random_pool(model, quant, jax.random.PRNGKey(1))
-    program = {"decode": model.paged_decode_step,
-               "verify": model.paged_verify,
-               "prefill": model.paged_prefill_chunk}[phase]
+    if phase == "decode":
+        # the one-token verify window, (B,) write targets as the engine has
+        def program(p, st, tb, ln, t, wp, wo, rules, **kw):
+            return model.paged_verify(p, st, tb, ln, t, wp[:, None],
+                                      wo[:, None], rules, **kw)
+    else:
+        program = {"verify": model.paged_verify,
+                   "prefill": model.paged_prefill_chunk}[phase]
     got = jax.jit(lambda p, st, *a: program(
         p, st, *a, None, use_pallas=use_pallas, quant=quant))(
         params, before, *args)

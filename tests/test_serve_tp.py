@@ -334,12 +334,12 @@ def test_tp_divisibility_validation():
     with every offending dimension named."""
     model = build_model(smoke_config("qwen2-7b"))     # hq=4, hkv=2
     with pytest.raises(ValueError, match="padded_kv_heads=2"):
-        model.validate_serve_tp(4)
-    model.validate_serve_tp(2)                        # 2 divides everything
-    model.validate_serve_tp(1)                        # tp=1 never validates
+        model.validate_serve_mesh(tp=4)
+    model.validate_serve_mesh(tp=2)                   # 2 divides everything
+    model.validate_serve_mesh(tp=1)                   # tp=1 never validates
     moe = build_model(smoke_config("qwen3-moe-235b-a22b"))  # E=8
     with pytest.raises(ValueError, match="n_experts=8"):
-        moe.validate_serve_tp(3)
+        moe.validate_serve_mesh(tp=3)
 
 
 def test_mesh_engine_argument_validation():

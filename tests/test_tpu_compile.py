@@ -127,13 +127,15 @@ def _paged_program(phase, width, one_chip, moe=False, kv_quant=None):
         args = (i32((CELL_P,)), i32((width // PAGE,)), i32(()),
                 i32((1, width)))
     else:
+        # decode is the one-token window, its (B,) targets expanded inside
         write = (b,) if phase == "decode" else (b, width)
-        step = (model.paged_decode_step if phase == "decode"
-                else model.paged_verify)
 
         def program(p, st, tb, ln, t, wp, wo, pl):
-            return step(p, st, tb, ln, t, wp, wo, None, use_pallas=True,
-                        quant=quant, placement=pl)
+            if phase == "decode":
+                wp, wo = wp[:, None], wo[:, None]
+            return model.paged_verify(p, st, tb, ln, t, wp, wo, None,
+                                      use_pallas=True, quant=quant,
+                                      placement=pl)
         args = (i32((b, CELL_P)), i32((b,)), i32((b, width)), i32(write),
                 i32(write))
     return program, (params, storage) + args + (place,), cfg
