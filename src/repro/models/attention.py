@@ -27,19 +27,22 @@ NEG_INF = -1e30
 
 
 def attention_def(cfg) -> dict:
-    """Parameter tree for one attention block (padded head counts)."""
+    """Parameter tree for one attention block (padded head counts).  The
+    projections and biases are read only as the compute dtype; the qk-norm
+    scales in float32."""
     d, hd = cfg.d_model, cfg.head_dim
     hq, hkv = cfg.padded_q_heads, cfg.padded_kv_heads
+    cast = functools.partial(Param, read_as_compute=True)
     defs = {
-        "wq": Param((d, hq, hd), P("embed_w", "q_heads", "head_dim")),
-        "wk": Param((d, hkv, hd), P("embed_w", "kv_heads", "head_dim")),
-        "wv": Param((d, hkv, hd), P("embed_w", "kv_heads", "head_dim")),
-        "wo": Param((hq, hd, d), P("q_heads", "head_dim", "embed_w")),
+        "wq": cast((d, hq, hd), P("embed_w", "q_heads", "head_dim")),
+        "wk": cast((d, hkv, hd), P("embed_w", "kv_heads", "head_dim")),
+        "wv": cast((d, hkv, hd), P("embed_w", "kv_heads", "head_dim")),
+        "wo": cast((hq, hd, d), P("q_heads", "head_dim", "embed_w")),
     }
     if cfg.qkv_bias:
-        defs["bq"] = Param((hq, hd), P("q_heads", "head_dim"), init="zeros")
-        defs["bk"] = Param((hkv, hd), P("kv_heads", "head_dim"), init="zeros")
-        defs["bv"] = Param((hkv, hd), P("kv_heads", "head_dim"), init="zeros")
+        defs["bq"] = cast((hq, hd), P("q_heads", "head_dim"), init="zeros")
+        defs["bk"] = cast((hkv, hd), P("kv_heads", "head_dim"), init="zeros")
+        defs["bv"] = cast((hkv, hd), P("kv_heads", "head_dim"), init="zeros")
     if cfg.qk_norm:
         defs["q_norm"] = L.rmsnorm_def(hd)
         defs["k_norm"] = L.rmsnorm_def(hd)

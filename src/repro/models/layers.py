@@ -75,9 +75,12 @@ def linear(params, x):
 
 def mlp_def(d_model: int, d_ff: int) -> dict:
     return {
-        "gate": Param((d_model, d_ff), P("embed_w", "mlp")),
-        "up": Param((d_model, d_ff), P("embed_w", "mlp")),
-        "down": Param((d_ff, d_model), P("mlp", "embed_w")),
+        "gate": Param((d_model, d_ff), P("embed_w", "mlp"),
+                      read_as_compute=True),
+        "up": Param((d_model, d_ff), P("embed_w", "mlp"),
+                    read_as_compute=True),
+        "down": Param((d_ff, d_model), P("mlp", "embed_w"),
+                      read_as_compute=True),
     }
 
 def mlp(params, x, *, activation=jax.nn.silu):

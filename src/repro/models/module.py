@@ -7,7 +7,9 @@ PartitionSpec + initializer).  From one declaration we derive:
 * abstract ``ShapeDtypeStruct`` trees with shardings for the dry-run
   (``abstract_params``) — no allocation ever happens for the 480B configs;
 * the sharding tree (``sharding_tree``) used as ``in_shardings`` for
-  ``train_step``/``serve_step``.
+  ``train_step``/``serve_step``;
+* the dtypes a server holds the weights in (``hold_params``): a leaf whose
+  every read casts it to the compute dtype is held in that dtype.
 
 This mirrors the paper's philosophy: the *declaration* is user code, the
 *distribution* (partitioning, placement) is generic machinery.
@@ -35,6 +37,9 @@ class Param:
     init: str = "normal"          # normal | zeros | ones | embed | small
     scale: float | None = None    # stddev override
     dtype: Any = None             # override model dtype
+    # every read of the leaf casts it to the compute dtype first (the
+    # model's ``dtype``), so a server may hold it in that dtype
+    read_as_compute: bool = False
 
     def stddev(self) -> float:
         if self.scale is not None:
@@ -98,3 +103,36 @@ def param_bytes(defs, dtype=jnp.float32) -> int:
     leaves = jax.tree_util.tree_leaves(defs, is_leaf=is_param)
     return int(sum(int(np.prod(p.shape)) * jnp.dtype(p.dtype or dtype).itemsize
                    for p in leaves))
+
+
+def tree_bytes(tree) -> int:
+    """Bytes of the arrays (or shapes) of ``tree``."""
+    return sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+def hold_params(defs, params, dtype):
+    """``(held, bytes_cast)``: ``params`` as a server holds them.  A float
+    leaf declared ``read_as_compute`` and stored wider than the compute
+    ``dtype`` is held in ``dtype``: every read rounds it to that anyway, so
+    the programs compute the same values.  Every other leaf (norm scales,
+    routers, anything read in float32) is held as stored.  The casts run in
+    one jitted call (a tree of shapes stays shapes, on the same shardings);
+    the caller's arrays are not donated.  ``bytes_cast`` is the bytes of
+    the leaves held in ``dtype``."""
+    dtype = jnp.dtype(dtype)
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    cast = [i for i, (p, a) in enumerate(zip(treedef.flatten_up_to(defs),
+                                             leaves))
+            if p.read_as_compute and jnp.issubdtype(a.dtype, jnp.floating)
+            and jnp.dtype(a.dtype).itemsize > dtype.itemsize]
+    if not cast:
+        return params, 0
+    if all(isinstance(leaves[i], jax.ShapeDtypeStruct) for i in cast):
+        new = [leaves[i].update(dtype=dtype) for i in cast]
+    else:
+        new = jax.jit(lambda xs: [x.astype(dtype) for x in xs])(
+            [leaves[i] for i in cast])
+    for i, a in zip(cast, new):
+        leaves[i] = a
+    return jax.tree_util.tree_unflatten(treedef, leaves), tree_bytes(new)

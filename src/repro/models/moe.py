@@ -39,18 +39,20 @@ PLACE_Q = 256
 
 def moe_def(cfg) -> dict:
     """The router keeps all ``n_experts`` outputs; the expert stacks hold
-    the config's share (``cfg.held_experts`` of them)."""
+    the config's share (``cfg.held_experts`` of them).  The expert stacks
+    are read only as the compute dtype; the router in float32."""
     d, E, eff = cfg.d_model, cfg.n_experts, cfg.expert_d_ff
     H, lo = cfg.held_experts, cfg.first_held_expert
     if not (0 < H and 0 <= lo and lo + H <= E):
         raise ValueError(
             f"{cfg.name}: held experts {lo}..{lo + H - 1} are not a range of "
             f"the router's {E} experts")
+    cast = partial(Param, read_as_compute=True)
     return {
         "router": Param((d, E), P("embed", None), init="small"),
-        "gate": Param((H, d, eff), P("experts", "expert_embed", "expert_mlp")),
-        "up": Param((H, d, eff), P("experts", "expert_embed", "expert_mlp")),
-        "down": Param((H, eff, d), P("experts", "expert_mlp", "expert_embed")),
+        "gate": cast((H, d, eff), P("experts", "expert_embed", "expert_mlp")),
+        "up": cast((H, d, eff), P("experts", "expert_embed", "expert_mlp")),
+        "down": cast((H, eff, d), P("experts", "expert_mlp", "expert_embed")),
     }
 
 

@@ -8,6 +8,7 @@ through logical ``constrain`` calls so the same code runs single-device
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -37,8 +38,8 @@ _SERIAL = SerialComm()
 def stack_defs(defs, n: int):
     """Prepend a stacked ``layers`` axis to every Param in a layer def tree."""
     def stack(p: Param) -> Param:
-        return Param((n,) + tuple(p.shape), P("layers", *p.spec), init=p.init,
-                     scale=p.scale, dtype=p.dtype)
+        return dataclasses.replace(p, shape=(n,) + tuple(p.shape),
+                                   spec=P("layers", *p.spec))
     return jax.tree_util.tree_map(stack, defs, is_leaf=lambda x: isinstance(x, Param))
 
 
@@ -60,11 +61,13 @@ def block_defs(cfg) -> dict:
 def transformer_defs(cfg) -> dict:
     return {
         "embed": {"table": Param((cfg.padded_vocab, cfg.d_model),
-                                 P("vocab", "embed_w"), init="small")},
+                                 P("vocab", "embed_w"), init="small",
+                                 read_as_compute=True)},
         "blocks": stack_defs(block_defs(cfg), cfg.n_layers),
         "final_norm": L.rmsnorm_def(cfg.d_model),
         "unembed": {"w": Param((cfg.d_model, cfg.padded_vocab),
-                               P("embed_w", "vocab"), init="small")},
+                               P("embed_w", "vocab"), init="small",
+                               read_as_compute=True)},
     }
 
 

@@ -70,6 +70,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.comm import Comm
 from repro.core.runtime import ThreadFarmExecutor
+from repro.models.module import hold_params, tree_bytes
 from repro.serve import pages as PG
 from repro.serve import quant as QZ
 from repro.serve import spec as SP
@@ -322,6 +323,7 @@ class ServeEngine:
         # Quantize BEFORE any device placement so only the int8 payload ever
         # lands in HBM; the full-precision weights are rebuilt transiently
         # inside each jitted call (dequant-on-apply).
+        weights_cast = 0
         if self.weight_quant:
             flt = [a for a in jax.tree_util.tree_leaves(params)
                    if hasattr(a, "dtype")
@@ -331,6 +333,12 @@ class ServeEngine:
             params = QZ.quantize_params(params)
             deq = functools.partial(QZ.dequantize_params, dtype=wq_dtype)
         else:
+            # a float leaf that every program reads through a cast to the
+            # compute dtype is held in that dtype: rounded once here
+            # instead of on every call, with the values the programs
+            # compute unchanged.  Placement below sees the held tree.
+            params, weights_cast = hold_params(model.param_defs(), params,
+                                               model.cfg.dtype)
             wq_src = None
             deq = lambda p: p                                   # noqa: E731
         self.params = params
@@ -452,6 +460,8 @@ class ServeEngine:
                       "kv_quant": self.kv_quant.name if self.kv_quant
                       else "off",
                       "weight_quant": self.weight_quant or "off",
+                      "weight_bytes": tree_bytes(self.params),
+                      "weight_bytes_cast": weights_cast,
                       "kv_bytes_per_token": QZ.kv_bytes_per_token(
                           model.paged_leaf_specs(kvq)) if self.paged else 0,
                       "moe_tokens_routed": 0, "moe_dropped_tokens": 0,

@@ -10,6 +10,7 @@ head_dim 128, d_model 2048), qwen2-7b's per-chip share at tp=4 (7 query /
 tokens per page with a 64-token prefill chunk.
 """
 import functools
+import math
 import os
 import re
 
@@ -86,9 +87,11 @@ def _paged_program(phase, width, one_chip, moe=False, kv_quant=None):
     of one slot).  ``moe`` builds the qwen3-moe-235b-a22b share of the MoE
     cell (8 held experts of 128, 128 slots, 9000 pages + trash) as the
     benchmark's configuration file states it; otherwise qwen3-1.7b at the
-    decode cell's shapes.  ``kv_quant`` is the pool's KV quantization."""
+    decode cell's shapes.  ``kv_quant`` is the pool's KV quantization.  The
+    params are the tree the engine holds (:func:`hold_params`)."""
     from repro.configs import get_config
     from repro.models.api import build_model
+    from repro.models.module import hold_params
     from repro.serve import placement as PL
     from repro.serve.quant import make_kv_quant
 
@@ -112,9 +115,9 @@ def _paged_program(phase, width, one_chip, moe=False, kv_quant=None):
         cfg = get_config("qwen3-1.7b")
         b, n_pages = CELL_B, CELL_PAGES
     model = build_model(cfg)
-    params = on_chip(jax.eval_shape(
+    params, _ = hold_params(model.param_defs(), on_chip(jax.eval_shape(
         lambda k: model.init(k, dtype=cfg.param_dtype),
-        jax.random.PRNGKey(0)))
+        jax.random.PRNGKey(0))), cfg.dtype)
     quant = make_kv_quant(kv_quant)
     storage = on_chip(model.paged_state_specs(n_pages - 1, PAGE, quant))
     place = i32(PL.identity_plan(cfg.n_experts, 1).dispatch_arrays().shape
@@ -227,6 +230,54 @@ def test_decode_step_runs_the_kernel_once_per_layer(one_chip, monkeypatch,
                for a in jax.tree_util.tree_leaves(storage))
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= temp_xs_ys - pool, (temp, pool)
+
+
+@pytest.mark.parametrize("case", ["decode", "verify", "prefill"])
+def test_dense_programs_convert_no_weight_stack(one_chip, monkeypatch, case):
+    """qwen3-1.7b's programs on the tree the engine holds, its matrices in
+    bfloat16: no convert makes a result of one layer's MLP matrix or more
+    (held in float32, each weight stack and the head were converted whole
+    on every call, and the temporaries held the bfloat16 copies: 2.8 GB),
+    and the temporaries are below one bfloat16 MLP stack."""
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    phase, width, moe, kv_quant, _ = PAGED_PROGRAMS[case]
+    program, args, cfg = _paged_program(phase, width, one_chip, moe,
+                                        kv_quant)
+    compiled = jax.jit(program, donate_argnums=(1,)).lower(*args).compile()
+    layer_mlp = cfg.d_model * cfg.d_ff
+    converts = [f"{n} = {dims} {op}" for n, dims, op
+                in _hlo_ops(compiled.as_text())
+                if "convert" in f"{n} {op}" and math.prod(dims) >= layer_mlp]
+    assert not converts, converts
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < cfg.n_layers * layer_mlp * 2, temp
+
+
+@pytest.mark.parametrize("case", ["moe-decode", "moe-prefill"])
+def test_moe_programs_are_those_of_the_stored_tree(one_chip, monkeypatch,
+                                                   case):
+    """The MoE share stores its parameters in bfloat16 already: the tree the
+    engine holds is the stored one, and so are its programs."""
+    from repro.kernels import ops as kops
+    from repro.models.api import build_model
+    from repro.models.module import hold_params
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    phase, width, moe, kv_quant, _ = PAGED_PROGRAMS[case]
+    program, args, cfg = _paged_program(phase, width, one_chip, moe,
+                                        kv_quant)
+    model = build_model(cfg)
+    stored = jax.tree_util.tree_map(
+        lambda a: _shape(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: model.init(k, dtype=cfg.param_dtype),
+                       jax.random.PRNGKey(0)))
+    held, cast = hold_params(model.param_defs(), stored, cfg.dtype)
+    assert cast == 0 and held is stored
+    assert jax.tree_util.tree_map(lambda a: a.dtype, args[0]) \
+        == jax.tree_util.tree_map(lambda a: a.dtype, stored)
+    lowered = [jax.jit(program, donate_argnums=(1,)).lower(
+        p, *args[1:]).as_text(debug_info=False) for p in (args[0], stored)]
+    assert lowered[0] == lowered[1]
 
 
 def test_rmsnorm_compiles_for_v5e(one_chip):
